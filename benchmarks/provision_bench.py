@@ -60,7 +60,6 @@ import time
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _ROOT)
 sys.path.insert(0, os.path.join(_ROOT, "src"))
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 from benchmarks.artifact import artifact, write_artifact  # noqa: E402
 

@@ -5,7 +5,7 @@ parsed and classified before any GA measurement narrows them further. This
 package is that stage for the jax_pallas port, in three layers:
 
 * :mod:`repro.analysis.jaxpr_walk` — traverse ``ClosedJaxpr``s (including
-  pjit/scan/while/cond sub-jaxprs), classify every equation and derive
+  jit/scan/while/cond sub-jaxprs), classify every equation and derive
   per-region FLOPs, HBM-byte proxies, arithmetic intensity and trip counts —
   the jaxpr analogue of the paper's Clang loop parse.
 * :mod:`repro.analysis.offload_lint` / :mod:`repro.analysis.kernel_lint` —

@@ -2,7 +2,7 @@
 
 This is the jaxpr analogue of the paper's Clang loop parse: instead of
 scanning C `for` statements for offloadable regions, we walk the
-``ClosedJaxpr`` of a traced program — recursing into ``pjit`` / ``scan`` /
+``ClosedJaxpr`` of a traced program — recursing into ``jit`` / ``scan`` /
 ``while`` / ``cond`` / ``remat`` sub-jaxprs — classify every equation
 (matmul / elementwise / scatter / collective / callback / kernel), and
 accumulate FLOPs, an HBM-byte proxy, and trip counts per region. The
@@ -32,7 +32,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
-from jax import core as jcore
+from jax.extend import core as jcore
 
 # ---------------------------------------------------------------------------
 # Equation classification
@@ -59,7 +59,7 @@ _CALLBACK_PRIMS = {
     "outside_call", "host_callback_call",
 }
 _CONTROL_PRIMS = {
-    "pjit", "xla_call", "closed_call", "core_call", "scan", "while", "cond",
+    "jit", "closed_call", "core_call", "scan", "while", "cond",
     "remat2", "remat", "checkpoint", "custom_jvp_call", "custom_vjp_call",
     "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr", "custom_lin",
     "named_call", "shard_map",
@@ -314,7 +314,7 @@ def _sub_jaxprs(eqn: Any) -> List[Tuple[str, jcore.ClosedJaxpr, float]]:
             if closed is not None:
                 out.append(("while.%s" % key.split("_")[0], closed, 1.0))
         return out
-    # Generic: recurse into any jaxpr-valued param (pjit, remat2, custom_*).
+    # Generic: recurse into any jaxpr-valued param (jit, remat2, custom_*).
     for key, val in sorted(eqn.params.items()):
         closed = _as_closed(val)
         if closed is not None:

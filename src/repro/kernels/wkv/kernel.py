@@ -34,21 +34,25 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_out_ref, state_ref,
     u = u_ref[0]  # (1, D) -> broadcast
     S = state_ref[...]  # (D, Dv)
 
-    cum = jnp.cumsum(lw, axis=0)  # (cs, D) inclusive
+    cs = r.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (cs, cs), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (cs, cs), 1)
+    # inclusive prefix sum over the chunk as a lower-triangular ones matmul:
+    # the chip's compiler has no cumsum lowering
+    cum = jnp.dot(jnp.where(col <= row, 1.0, 0.0), lw,
+                  precision=jax.lax.Precision.HIGHEST,
+                  preferred_element_type=jnp.float32)  # (cs, D)
     q_dec = jnp.exp(cum - lw)  # decay chunk-start -> t-1
     k_dec = jnp.exp(-cum)
     A = (r * q_dec) @ (k * k_dec).T  # (cs, cs)
-    cs = r.shape[0]
-    tri = jax.lax.broadcasted_iota(jnp.int32, (cs, cs), 1) < \
-        jax.lax.broadcasted_iota(jnp.int32, (cs, cs), 0)
-    A = jnp.where(tri, A, 0.0)
-    diag = jnp.sum(r * u * k, axis=-1)  # (cs,)
-    out = A @ v + diag[:, None] * v
+    A = jnp.where(col < row, A, 0.0)
+    diag = jnp.sum(r * u * k, axis=-1, keepdims=True)  # (cs, 1)
+    out = A @ v + diag * v
     out = out + (r * q_dec) @ S
 
-    total = cum[-1]  # (D,)
-    carry_k = k * jnp.exp(total[None, :] - cum)
-    state_ref[...] = S * jnp.exp(total)[:, None] + carry_k.T @ v
+    total = cum[cs - 1:]  # (1, D)
+    carry_k = k * jnp.exp(total - cum)
+    state_ref[...] = S * jnp.exp(total).T + carry_k.T @ v
     o_ref[0] = out.astype(o_ref.dtype)
 
     @pl.when(ci == num_chunks - 1)

@@ -33,6 +33,7 @@ from repro.configs import SHAPES, cell_supported, get_config, list_configs
 from repro.configs.base import ArchConfig, ShapeSpec
 from repro.core.hlo_analysis import collective_stats
 from repro.core.lm_cost_model import Decisions
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import chips, make_production_mesh, mesh_shape_dict
 from repro.launch.steps import build_cell_program
 from repro.parallel.layouts import rules_for
@@ -231,6 +232,7 @@ def main() -> None:
     ap.add_argument("--out", default="results/dryrun")
     ap.add_argument("--skip-probes", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     archs = list_configs() if args.arch == "all" else [args.arch]
     shapes = list(SHAPES) if args.shape == "all" else [args.shape]

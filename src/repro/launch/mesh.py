@@ -4,10 +4,9 @@ A FUNCTION (not a module-level constant) so importing this module never
 touches jax device state. Single pod: 16×16 = 256 chips (data, model);
 multi-pod: 2×16×16 = 512 chips (pod, data, model).
 
-``make_mesh_compat`` papers over the jax API drift around explicit axis
-types: jax ≥ 0.6 takes ``axis_types=(AxisType.Auto, ...)``, older releases
-(the 0.4.x line in this container) take no such kwarg and treat every axis
-as auto. All mesh construction in src/ and tests/ goes through it.
+``make_mesh_compat`` builds every mesh in src/ and tests/ with ``Auto``
+axis types, so sharding is propagated by the compiler (GSPMD) rather than
+made explicit in the program.
 """
 from __future__ import annotations
 
@@ -15,12 +14,9 @@ import jax
 
 
 def make_mesh_compat(shape, axes):
-    """jax.make_mesh across jax versions (Auto axis types when supported)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis of type ``Auto``."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -32,6 +32,7 @@ import jax
 from repro.configs import get_config, mixed_fleet, reduced as reduce_cfg
 from repro import models as M
 from repro.core.ga import GAConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime import FleetRouter, PlacementController, Request, \
     ServingEngine, static_placements
 from repro.runtime.placement import DEFAULT_MESH_OPTIONS
@@ -213,6 +214,7 @@ def main() -> None:
                          "this nameplate watt budget, instead of the whole "
                          "catalog")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.provision_budget_w is not None and not args.fleet:
         ap.error("--provision-budget-w requires --fleet")
     if args.fleet:

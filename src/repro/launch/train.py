@@ -22,6 +22,7 @@ from repro.configs import SHAPES, get_config, reduced as reduce_cfg
 from repro.configs.base import ShapeSpec
 from repro.core import Decisions, GAConfig, search_lm_cell
 from repro.data import DataConfig, SyntheticLMStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import build_train_step, init_train_state
 from repro.parallel.layouts import rules_for
 from repro.parallel.sharding import use_mesh
@@ -138,6 +139,7 @@ def main() -> None:
     ap.add_argument("--search-first", action="store_true",
                     help="run the GA offload search before training")
     args = ap.parse_args()
+    enable_compile_cache()
     out = train(args.arch, use_reduced=not args.full, steps=args.steps,
                 global_batch=args.global_batch, seq_len=args.seq_len,
                 checkpoint_dir=args.checkpoint_dir,

@@ -18,14 +18,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.6
-    from jax import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
 
 
 def pipeline_apply(
@@ -63,8 +57,8 @@ def pipeline_apply(
         jax.tree.map(lambda _: P(axis), stacked_params),
         P(),  # microbatch stream replicated across stages
     )
-    fn = _shard_map(per_stage, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                    **_SHARD_MAP_KW)
+    fn = shard_map(per_stage, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                   check_vma=False)
     return fn(stacked_params, microbatches)
 
 

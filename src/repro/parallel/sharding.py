@@ -105,10 +105,7 @@ def use_mesh(mesh: Optional[Mesh], rules: Optional[ShardingRules] = None):
     _CTX.mesh, _CTX.rules = mesh, rules
     try:
         if mesh is not None:
-            set_mesh = getattr(jax, "set_mesh", None)
-            # jax < 0.6: no ambient-mesh setter; entering the Mesh context
-            # gives the same named-axis environment to lowered programs.
-            with (set_mesh(mesh) if set_mesh is not None else mesh):
+            with jax.set_mesh(mesh):
                 yield
         else:
             yield

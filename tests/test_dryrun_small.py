@@ -40,8 +40,6 @@ SCRIPT = textwrap.dedent("""
             compiled = prog.lower().compile()
         ma = compiled.memory_analysis()
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # jax 0.4.x: one dict per program
-            ca = ca[0] if ca else {}
         out[f"{arch}/{shape.kind}"] = {
             "flops": float(ca.get("flops", 0)),
             "temp": int(ma.temp_size_in_bytes),
