@@ -62,6 +62,7 @@ the telemetry loop and the fleet router fit together.
 """
 from __future__ import annotations
 
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Mapping, Optional
@@ -72,6 +73,8 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.models import transformer as T
+from repro.runtime import spans
+from repro.runtime.spans import span
 
 
 @dataclass
@@ -103,6 +106,10 @@ class Request:
     # serve CLI reports per request
     served_by: Optional[str] = None
     destination: Optional[str] = None
+    # time.perf_counter stamps, each set once: prefill is admit -> first
+    # output token
+    admit_t: Optional[float] = None
+    first_token_t: Optional[float] = None
 
 
 @dataclass
@@ -138,6 +145,17 @@ class EngineStats:
     # migrated request's tokens bill once (pre-move under the source epoch,
     # post-move under the target's) and the move itself bills here.
     migration_ws: float = 0.0
+    # host seconds of each phase of stream_step (runtime/spans.py), in
+    # order
+    admit_s: float = 0.0
+    reset_s: float = 0.0
+    feed_s: float = 0.0
+    decode_s: float = 0.0
+    pull_s: float = 0.0
+    emit_s: float = 0.0
+    # per stream step, the sum over active slots of the cache rows the
+    # step's attention sees (cursor + 1): live rows against reserved
+    live_row_steps: int = 0
 
     @property
     def occupancy(self) -> float:
@@ -158,6 +176,12 @@ class EngineStats:
     def snapshot(self) -> "EngineStats":
         return EngineStats(**{f: getattr(self, f)
                               for f in self.__dataclass_fields__})
+
+
+#: EngineStats fields read off the wall clock: two runs of the same requests
+#: differ in them, so ledger comparisons leave them out
+CLOCK_FIELDS = ("admit_s", "reset_s", "feed_s", "decode_s", "pull_s",
+                "emit_s")
 
 
 @dataclass(frozen=True)
@@ -250,11 +274,12 @@ class ServingEngine:
         # Donating the state matches launch/steps.build_serve_step: the old
         # KV/recurrent buffers are dead after every call site (both the
         # stream and wave paths rebind), so XLA updates the cache in place
-        # instead of paying a copy + double HBM residency per token.
-        self._step = jax.jit(
-            lambda params, state, tokens: T.decode_step(cfg, params, state,
-                                                        tokens),
-            donate_argnums=(1,))
+        # instead of paying a copy + double HBM residency per token. A named
+        # function names the device program: jit_decode_step.
+        def decode_step(params, state, tokens):
+            return T.decode_step(cfg, params, state, tokens)
+
+        self._step = jax.jit(decode_step, donate_argnums=(1,))
 
     def submit(self, req: Request) -> bool:
         """Admit a request; False when rejected (empty prompt, a prompt the
@@ -460,6 +485,8 @@ class ServingEngine:
         """Common admission bookkeeping (both schedulers)."""
         if req.status == "queued":
             req.status = "active"
+        if req.admit_t is None:
+            req.admit_t = time.perf_counter()
         req.modeled_latency_s = self.modeled_latency_s(req)
         req.served_by = self.name
         billed = self.placements.get("decode") or self.placements.get("prefill")
@@ -515,6 +542,7 @@ class ServingEngine:
             # preserved by mid-flight migration (see _finish_reason)
             "cap": [self.max_len] * self.slots,
         }
+        spans.GC.acquire()  # names collector pauses while a session is open
 
     def stream_busy(self) -> bool:
         """True while the open session has queued or in-slot work."""
@@ -538,72 +566,85 @@ class ServingEngine:
         s = self._stream
         slot_req, cursors, slot_epoch = s["slot_req"], s["cursors"], s["epoch"]
         caps = s["cap"]
-        # admission: every free slot takes the next queued request — a
-        # slot freed on step t serves its new request on step t+1
-        newly = []
-        for i in range(self.slots):
-            if slot_req[i] is None and self.queue:
-                req = self.queue.popleft()
-                slot_req[i] = req
-                cursors[i] = 0
-                slot_epoch[i] = dict(self.placements)
-                caps[i] = self.max_len
-                self._admit(req)
-                newly.append(i)
+        stats, k = self.stats, self.stats.steps
+        # the step's phases, each timed by a span (runtime/spans.py)
+        with span(stats, "admit", k):
+            # admission: every free slot takes the next queued request — a
+            # slot freed on step t serves its new request on step t+1
+            newly = []
+            for i in range(self.slots):
+                if slot_req[i] is None and self.queue:
+                    req = self.queue.popleft()
+                    slot_req[i] = req
+                    cursors[i] = 0
+                    slot_epoch[i] = dict(self.placements)
+                    caps[i] = self.max_len
+                    self._admit(req)
+                    newly.append(i)
         if not any(r is not None for r in slot_req):
             return None
         if newly:
-            mask = np.zeros((self.slots,), bool)
-            mask[newly] = True
-            s["state"] = T.reset_decode_slots(self.cfg, s["state"],
-                                              jnp.asarray(mask))
-        step_s = 0.0
-        tokens = np.zeros((self.slots,), np.int32)
-        for i, req in enumerate(slot_req):
-            if req is None:
-                continue
-            c = cursors[i]
-            tokens[i] = (req.prompt[c] if c < len(req.prompt)
-                         else req.output[-1])
-            kind = "prefill" if c < len(req.prompt) else "decode"
-            p = slot_epoch[i].get(kind)
-            if p is not None:
-                step_s = max(step_s, p.time_per_token_s)
-        self.last_step_s = step_s
-        logits, s["state"] = self._step(self.params, s["state"],
-                                        jnp.asarray(tokens))
-        self.stats.steps += 1
-        self.stats.slot_steps += self.slots
-        self.stats.active_slot_steps += sum(r is not None for r in slot_req)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        done: list[Request] = []
-        for i, req in enumerate(slot_req):
-            if req is None:
-                continue
-            c = cursors[i]
-            cursors[i] += 1
-            # the step consuming a prompt token is PREFILL — including
-            # the one consuming the last prompt token (which already
-            # emits the first output token): a length-L prompt
-            # contributes exactly L prefill tokens
-            if c < len(req.prompt):
-                self.stats.prefill_tokens += 1
-                self.stats.energy_ws += self._token_energy(
-                    "prefill", slot_epoch[i])
-            else:
-                self.stats.decode_tokens += 1
-                self.stats.energy_ws += self._token_energy(
-                    "decode", slot_epoch[i])
-            if c >= len(req.prompt) - 1:  # this step emitted a token
-                tok = int(nxt[i])
-                req.output.append(tok)
-                reason = self._finish_reason(req, tok, cursors[i], caps[i])
-                if reason is not None:
-                    self._finish(req, reason)
-                    done.append(req)
-                    slot_req[i] = None  # freed; refilled next step
-        if self.on_step_end is not None:
-            self.on_step_end(self)
+            with span(stats, "reset", k):
+                mask = np.zeros((self.slots,), bool)
+                mask[newly] = True
+                s["state"] = T.reset_decode_slots(self.cfg, s["state"],
+                                                  jnp.asarray(mask))
+        with span(stats, "feed", k):
+            step_s = 0.0
+            tokens = np.zeros((self.slots,), np.int32)
+            for i, req in enumerate(slot_req):
+                if req is None:
+                    continue
+                c = cursors[i]
+                tokens[i] = (req.prompt[c] if c < len(req.prompt)
+                             else req.output[-1])
+                kind = "prefill" if c < len(req.prompt) else "decode"
+                p = slot_epoch[i].get(kind)
+                if p is not None:
+                    step_s = max(step_s, p.time_per_token_s)
+            self.last_step_s = step_s
+        with span(stats, "decode", k):
+            logits, s["state"] = self._step(self.params, s["state"],
+                                            jnp.asarray(tokens))
+        stats.steps += 1
+        stats.slot_steps += self.slots
+        stats.active_slot_steps += sum(r is not None for r in slot_req)
+        with span(stats, "pull", k):
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        with span(stats, "emit", k):
+            done: list[Request] = []
+            now = time.perf_counter()
+            for i, req in enumerate(slot_req):
+                if req is None:
+                    continue
+                c = cursors[i]
+                cursors[i] += 1
+                stats.live_row_steps += c + 1
+                # the step consuming a prompt token is PREFILL — including
+                # the one consuming the last prompt token (which already
+                # emits the first output token): a length-L prompt
+                # contributes exactly L prefill tokens
+                if c < len(req.prompt):
+                    stats.prefill_tokens += 1
+                    stats.energy_ws += self._token_energy(
+                        "prefill", slot_epoch[i])
+                else:
+                    stats.decode_tokens += 1
+                    stats.energy_ws += self._token_energy(
+                        "decode", slot_epoch[i])
+                if c >= len(req.prompt) - 1:  # this step emitted a token
+                    tok = int(nxt[i])
+                    req.output.append(tok)
+                    if req.first_token_t is None:
+                        req.first_token_t = now
+                    reason = self._finish_reason(req, tok, cursors[i],
+                                                 caps[i])
+                    if reason is not None:
+                        self._finish(req, reason)
+                        done.append(req)
+                        slot_req[i] = None  # freed; refilled next step
+            if self.on_step_end is not None:
+                self.on_step_end(self)
         return done
 
     def stream_close(self) -> None:
@@ -620,6 +661,7 @@ class ServingEngine:
                 self.stats.incomplete += 1
                 self.active.remove(req)
         self._stream = None
+        spans.GC.release()
 
     # ------------------------------------------------------------------
     # Mid-flight migration (runtime/migration.py holds the machinery)
@@ -726,6 +768,8 @@ class ServingEngine:
             if c >= len(req.prompt) - 1:
                 tok = int(nxt[i])
                 req.output.append(tok)
+                if req.first_token_t is None:
+                    req.first_token_t = time.perf_counter()
                 reason = self._finish_reason(req, tok, cursors[i],
                                              w["cap"][i])
                 if reason is not None:

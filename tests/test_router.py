@@ -16,6 +16,7 @@ from repro.core.pareto import (
 )
 from repro import models as M
 from repro.runtime import FleetRouter, Request, ServingEngine
+from repro.runtime.serving import CLOCK_FIELDS
 
 GA = GAConfig(population=8, generations=6, seed=0)
 MIXED = ("pod2_v5e", "mxu_dense", "hbm_lp")
@@ -399,7 +400,8 @@ def test_autoscale_flag_changes_nothing_without_a_clock(small_model,
     assert outs[False] == outs[True]
     a, b = legacy.fleet_stats(), scaled.fleet_stats()
     for f in type(a).__dataclass_fields__:
-        assert getattr(a, f) == getattr(b, f), f
+        if f not in CLOCK_FIELDS:
+            assert getattr(a, f) == getattr(b, f), f
     assert a.idle_ws == 0.0 and a.wakes == 0 and a.sleeps == 0
 
 
