@@ -189,6 +189,19 @@ def cache_logical_axes() -> dict:
     }
 
 
+def _write_row(cache: jax.Array, row: jax.Array, slot: jax.Array) -> jax.Array:
+    """``cache`` (B, T, K, hd) with ``row`` (B, 1, K, hd), cast to the cache's
+    dtype, written at sequence index ``slot[b]`` of each batch row ``b``.
+
+    A one-hot select over the sequence axis, not a scatter: a scatter makes
+    the compiler copy the layer's slice into a row-major layout and back,
+    while the select fuses into the attention dots and into the in-place
+    write of the slice that ``decode_step``'s layer loop makes.
+    """
+    hit = jnp.arange(cache.shape[1])[None, :] == slot[:, None]
+    return jnp.where(hit[:, :, None, None], row.astype(cache.dtype), cache)
+
+
 def decode_attention(
     cfg: ArchConfig,
     p: dict,
@@ -204,6 +217,13 @@ def decode_attention(
     stream) or a (B,) vector (per-slot position streams: each batch row
     carries its own stream, so continuous-batching slots never alias cache
     positions across the requests sharing a slot). Returns (out, new_cache).
+
+    ``cache`` is one layer's slice of the stacked self-attention cache;
+    ``new_cache`` is that slice with each slot's new K and V row written at
+    ``pos`` (``pos % length`` in a sliding-window ring) by :func:`_write_row`,
+    in the cache's bfloat16. ``decode_step`` writes it back into the stack
+    at the layer's index, so under donation the cache is updated in place.
+    Cross-attention (``kv_memory``) is read only and returns ``cache`` as is.
 
     The cache sequence axis is sharded ("kv_seq"); softmax statistics combine
     across shards via GSPMD all-reduce (flash-decode style SP).
@@ -227,9 +247,8 @@ def decode_attention(
             k_new = apply_rope(k_new, pos[:, None], cfg.rope_theta)
         length = cache["k"].shape[1]
         slot = (pos % length) if window else pos
-        rows = jnp.arange(b)
-        k = cache["k"].at[rows, slot].set(k_new[:, 0].astype(cache["k"].dtype))
-        v = cache["v"].at[rows, slot].set(v_new[:, 0].astype(cache["v"].dtype))
+        k = _write_row(cache["k"], k_new, slot)
+        v = _write_row(cache["v"], v_new, slot)
         k = shard_act(k, ("kv_batch", "kv_seq", "act_kv_heads", None), essential=True)
         v = shard_act(v, ("kv_batch", "kv_seq", "act_kv_heads", None), essential=True)
         new_cache = {"k": k, "v": v}

@@ -348,6 +348,13 @@ def forward_loss(cfg: ArchConfig, params: dict, batch: dict, *,
 
 
 def init_decode_state(cfg: ArchConfig, batch: int, cache_len: int) -> dict:
+    """Zeroed decode state for ``batch`` slots. Self-attention caches are
+    stacked ``(layers, batch, cache_len, kv_heads, head_dim)`` bfloat16
+    leaves (``cache_len`` capped at the sliding window, a ring). Each
+    ``decode_step`` writes one row a slot into them in place: a one-hot
+    select in ``decode_attention``, and the layer loop's write of the slice
+    back at its index (``_scan_layers_in_place``); with the state donated,
+    as ``ServingEngine`` does, no copy of the cache is made."""
     # "pos" is a (batch,) vector: every slot carries its OWN position stream
     # so a serving slot can be reset (reset_decode_slots) and re-admitted
     # mid-stream without aliasing cache positions across requests. Uniform
@@ -524,6 +531,31 @@ def restore_decode_slot(cfg: ArchConfig, state: dict, slot: int,
     return new_state
 
 
+def _scan_layers_in_place(body, x, xs, cache):
+    """``lax.scan`` over stacked layers with the stacked self-attention cache
+    in the carry, not in ``xs``/``ys``.
+
+    ``body(x, xs_l, cache_l) -> (x, cache_l, ys_l)`` sees layer ``l``'s slice
+    of every cache leaf; the slice it returns is written back at index ``l``
+    of the carried stack. The stack therefore never passes through a fresh
+    output stack, and under donation the input buffer is the output buffer:
+    no whole-cache copy. Returns ``(x, cache, ys)``.
+    """
+    def step(carry, xs_l):
+        x, cache, l = carry
+        cache_l = jax.tree.map(
+            lambda c: jax.lax.dynamic_index_in_dim(c, l, 0, keepdims=False),
+            cache)
+        x, cache_l, ys = body(x, xs_l, cache_l)
+        cache = jax.tree.map(
+            lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, l, 0),
+            cache, cache_l)
+        return (x, cache, l + 1), ys
+
+    (x, cache, _), ys = jax.lax.scan(step, (x, cache, jnp.int32(0)), xs)
+    return x, cache, ys
+
+
 def decode_step(cfg: ArchConfig, params: dict, state: dict, tokens: jax.Array
                 ) -> tuple[jax.Array, dict]:
     """tokens: (B,) int32 — one step. Returns (logits (B, V), new_state).
@@ -561,8 +593,8 @@ def decode_step(cfg: ArchConfig, params: dict, state: dict, tokens: jax.Array
         mamba_states = jax.tree.map(
             lambda v: v.reshape((ng, ae) + v.shape[1:]), state["mamba"])
 
-        def gbody(x, inp):
-            p_g, kv_g, m_g = inp
+        def gbody(x, inp, kv_g):
+            p_g, m_g = inp
             xn = L.rms_norm(x, shared["ln"], cfg.norm_eps)
             y, kv_new = attn.decode_attention(cfg, shared["attn"], xn, kv_g, pos)
             x = x + y
@@ -575,10 +607,10 @@ def decode_step(cfg: ArchConfig, params: dict, state: dict, tokens: jax.Array
                 x = x + y
                 m_new.append(m_i2)
             m_new = jax.tree.map(lambda *vs: jnp.stack(vs), *m_new)
-            return x, (kv_new, m_new)
+            return x, kv_new, m_new
 
-        x, (kv_new, m_new) = jax.lax.scan(
-            gbody, x, (params["groups"], state["attn"], mamba_states))
+        x, kv_new, m_new = _scan_layers_in_place(
+            gbody, x, (params["groups"], mamba_states), state["attn"])
         new_state["attn"] = kv_new
         new_state["mamba"] = jax.tree.map(
             lambda v: v.reshape((ng * ae,) + v.shape[2:]), m_new)
@@ -593,8 +625,8 @@ def decode_step(cfg: ArchConfig, params: dict, state: dict, tokens: jax.Array
                                      (params["tail"], state["mamba_tail"]))
             new_state["mamba_tail"] = mt_new
     elif cfg.is_encdec:
-        def body(x, inp):
-            p_l, kv_l, ck, cv = inp
+        def body(x, inp, kv_l):
+            p_l, ck, cv = inp
             xn = L.rms_norm(x, p_l["ln1"], cfg.norm_eps)
             y, kv_new = attn.decode_attention(cfg, p_l["attn"], xn, kv_l, pos)
             x = x + y
@@ -604,17 +636,16 @@ def decode_step(cfg: ArchConfig, params: dict, state: dict, tokens: jax.Array
             x = x + y
             xn = L.rms_norm(x, p_l["ln2"], cfg.norm_eps)
             x = x + L.mlp_apply(cfg, p_l["mlp"], xn)
-            return x, kv_new
+            return x, kv_new, None
 
-        x, kv_new = jax.lax.scan(
-            body, x, (params["layers"], state["self"],
-                      state["cross_k"], state["cross_v"]))
+        x, kv_new, _ = _scan_layers_in_place(
+            body, x, (params["layers"], state["cross_k"], state["cross_v"]),
+            state["self"])
         new_state["self"] = kv_new
         new_state["cross_k"] = state["cross_k"]
         new_state["cross_v"] = state["cross_v"]
     else:
-        def body(x, inp):
-            p_l, kv_l = inp
+        def body(x, p_l, kv_l):
             xn = L.rms_norm(x, p_l["ln1"], cfg.norm_eps)
             y, kv_new = attn.decode_attention(
                 cfg, p_l["attn"], xn, kv_l, pos, window=cfg.sliding_window)
@@ -624,9 +655,10 @@ def decode_step(cfg: ArchConfig, params: dict, state: dict, tokens: jax.Array
                 y2, _ = moe_mod.moe_apply(cfg, p_l["moe"], xn)
             else:
                 y2 = L.mlp_apply(cfg, p_l["mlp"], xn)
-            return x + y2, kv_new
+            return x + y2, kv_new, None
 
-        x, kv_new = jax.lax.scan(body, x, (params["layers"], state["kv"]))
+        x, kv_new, _ = _scan_layers_in_place(body, x, params["layers"],
+                                             state["kv"])
         new_state["kv"] = kv_new
 
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)

@@ -9,6 +9,9 @@ fixed request set — across architecture families, including the recurrent
 prefill/decode attribution, finish reasons, deque queue draining,
 placement-epoch energy attribution, SLO-aware admission.
 """
+import dataclasses
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,6 +19,8 @@ import pytest
 
 from repro.configs import get_config, reduced
 from repro import models as M
+from repro.models import attention as attn_mod
+from repro.models import transformer as T
 from repro.runtime import Placement, Request, ServingEngine
 
 
@@ -284,3 +289,80 @@ def test_mid_run_submit_is_admitted_next_step(small_model):
     eng.submit(Request(rid=0, prompt=[1, 2, 3], max_new_tokens=4))
     done = eng.run()
     assert {r.rid for r in done} == {0, 99}
+
+
+# ---------------------------------------------------------------------------
+# Exactness: the in-place cache path == the scatter-and-stack path
+# ---------------------------------------------------------------------------
+
+
+def _scatter_row(cache, row, slot):
+    """The row write the in-place path replaced: a scatter of each batch
+    row's new K or V row at its slot."""
+    rows = jnp.arange(cache.shape[0])
+    return cache.at[rows, slot].set(row[:, 0].astype(cache.dtype))
+
+
+def _scan_layers_xs_ys(body, x, xs, cache):
+    """The layer loop the in-place path replaced: the stacked cache passes
+    through the scan as ``xs`` and comes back as ``ys``."""
+    def step(x, inp):
+        xs_l, cache_l = inp
+        x, cache_l, ys = body(x, xs_l, cache_l)
+        return x, (cache_l, ys)
+
+    x, (cache, ys) = jax.lax.scan(step, x, (xs, cache))
+    return x, cache, ys
+
+
+@pytest.mark.parametrize("arch,layers", [
+    ("stablelm-1.6b", 2),        # dense
+    ("mixtral-8x7b", 2),         # MoE, sliding-window ring of 32
+    ("zamba2-7b", 5),            # hybrid: two shared-attention groups, a tail
+    ("seamless-m4t-medium", 2),  # encoder-decoder self-attention
+])
+def test_in_place_cache_matches_scatter_and_stack(arch, layers, monkeypatch):
+    """Every family with a self-attention cache gives the same logits and
+    the same state, leaf for leaf and bit for bit, as the scatter write and
+    the ``xs``/``ys`` layer scan: both write the same bfloat16 row at the
+    same position, and the attention reads the same values. Slots run at
+    positions that differ, one is reset to 0 mid-stream and re-admitted,
+    and in the ring two slots wrap past its 32 rows."""
+    cfg = dataclasses.replace(reduced(get_config(arch)), num_layers=layers)
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    slots, cache_len, steps = 3, 48, 44
+    state0 = M.init_decode_state(cfg, slots, cache_len)
+    if cfg.is_encdec:  # an encoder memory to attend to, not zeros
+        k1, k2 = jax.random.split(jax.random.PRNGKey(1))
+        shape = state0["cross_k"].shape
+        state0["cross_k"] = jax.random.normal(k1, shape).astype(jnp.bfloat16)
+        state0["cross_v"] = jax.random.normal(k2, shape).astype(jnp.bfloat16)
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (steps, slots), 0,
+                                cfg.vocab_size)
+    # slots 1 and 2 (re)start at steps 5 and 9; slot 0 restarts at step 20
+    resets = {5: [False, True, False], 9: [False, False, True],
+              20: [True, False, False]}
+
+    def serve():
+        step = jax.jit(functools.partial(M.decode_step, cfg))
+        st, logits = state0, []
+        for t in range(steps):
+            if t in resets:
+                st = M.reset_decode_slots(cfg, st, jnp.array(resets[t]))
+            lg, st = step(params, st, tokens[t])
+            logits.append(lg)
+        return jnp.stack(logits), st
+
+    logits, state = serve()
+    with monkeypatch.context() as m:
+        m.setattr(T, "_scan_layers_in_place", _scan_layers_xs_ys)
+        m.setattr(attn_mod, "_write_row", _scatter_row)
+        ref_logits, ref_state = serve()
+
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(ref_logits))
+    assert jax.tree.structure(state) == jax.tree.structure(ref_state)
+    for leaf, ref in zip(jax.tree.leaves(state), jax.tree.leaves(ref_state)):
+        assert leaf.shape == ref.shape and leaf.dtype == ref.dtype
+        np.testing.assert_array_equal(np.asarray(leaf), np.asarray(ref))
+    # the next positions: slots 1 and 2 have wrapped mixtral's ring of 32
+    np.testing.assert_array_equal(np.asarray(state["pos"]), [24, 39, 35])
