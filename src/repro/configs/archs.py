@@ -1,4 +1,5 @@
-"""The 10 assigned architectures (+ the paper's own Himeno workload config).
+"""The 10 assigned architectures and granite-4.0-h-small (+ the paper's own
+Himeno workload config).
 
 Sources are the public configs cited in the assignment; ``accum`` /
 ``remat`` / ``accum_dtype`` are *this framework's* memory-fit policy for the
@@ -38,6 +39,22 @@ ZAMBA2_7B = register(ArchConfig(
     ssm_state=64, ssm_head_dim=64, ssm_expand=2, conv_kernel=4,
     attn_every=6,  # Mamba2 backbone + shared attention block every 6 blocks
     accum=4,
+))
+
+# Granite 4.0-H Small (GraniteMoeHybrid, 32B-A9B; ibm-granite/
+# granite-4.0-h-small config.json): attention at index 5 of every 10 layers,
+# NoPE, muP multipliers, and after every mixer 72 experts (top 10) plus a
+# shared expert. d_ff is one expert's width.
+GRANITE_4_0_H_SMALL = register(ArchConfig(
+    name="granite-4.0-h-small", family="moe_hybrid",
+    num_layers=40, d_model=4096, num_heads=32, num_kv_heads=8, head_dim=128,
+    d_ff=768, vocab_size=100352,
+    num_experts=72, experts_per_token=10, shared_expert_ff=1536,
+    ssm_state=128, ssm_head_dim=64, ssm_expand=2, conv_kernel=4,
+    layer_pattern="MMMMMAMMMM",
+    rope=False, attention_multiplier=0.0078125,
+    embedding_multiplier=12.0, residual_multiplier=0.22, logits_scaling=16.0,
+    tie_embeddings=True,
 ))
 
 RWKV6_1_6B = register(ArchConfig(
@@ -103,5 +120,5 @@ LLAVA_NEXT_MISTRAL_7B = register(ArchConfig(
 ALL_ARCH_NAMES = [
     "mixtral-8x7b", "grok-1-314b", "zamba2-7b", "granite-20b",
     "stablelm-1.6b", "qwen1.5-110b", "llama3.2-3b", "rwkv6-1.6b",
-    "seamless-m4t-medium", "llava-next-mistral-7b",
+    "seamless-m4t-medium", "llava-next-mistral-7b", "granite-4.0-h-small",
 ]

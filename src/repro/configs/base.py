@@ -52,7 +52,7 @@ SHAPES: dict[str, ShapeSpec] = {
 @dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str  # dense | moe | hybrid | ssm | audio | vlm
+    family: str  # dense | moe | hybrid | moe_hybrid | ssm | audio | vlm
     num_layers: int
     d_model: int
     num_heads: int  # 0 => attention-free family
@@ -62,9 +62,11 @@ class ArchConfig:
     head_dim: int = 0  # 0 => d_model // num_heads
 
     # --- MoE ---
-    num_experts: int = 0
+    num_experts: int = 0  # experts the router chooses among
     experts_per_token: int = 0
     capacity_factor: float = 1.25
+    experts_held: int = 0  # experts whose weights this chip holds; 0 => all
+    shared_expert_ff: int = 0  # width of an always-on shared expert; 0 => none
 
     # --- SSM (Mamba2) ---
     ssm_state: int = 0
@@ -72,6 +74,10 @@ class ArchConfig:
     ssm_expand: int = 2
     conv_kernel: int = 4
     attn_every: int = 0  # hybrid: shared attention block after every N blocks
+    # moe_hybrid: the mixers of one period of layers, "M" Mamba2 and "A"
+    # attention, each layer followed by the expert layer; num_layers is a
+    # whole number of periods
+    layer_pattern: str = ""
 
     # --- RWKV ---
     rwkv_head_size: int = 64
@@ -81,6 +87,13 @@ class ArchConfig:
     sliding_window: int = 0  # 0 = full attention
     qkv_bias: bool = False
     rope_theta: float = 10_000.0
+    rope: bool = True  # False: no position embedding (NoPE)
+    attention_multiplier: float = 0.0  # score scale; 0 => head_dim ** -0.5
+
+    # --- muP multipliers (1.0 leaves the stream as it is) ---
+    embedding_multiplier: float = 1.0  # on the token embedding
+    residual_multiplier: float = 1.0  # on each block's output, before the add
+    logits_scaling: float = 1.0  # the logits are divided by it
 
     # --- encoder/decoder + modality frontends ---
     encoder_layers: int = 0  # >0 => encoder-decoder
@@ -117,6 +130,17 @@ class ArchConfig:
     @property
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_head_dim if self.ssm_state else 0
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def layer_types(self) -> tuple[str, ...]:
+        """moe_hybrid: each layer's mixer, "mamba" or "attention"."""
+        kinds = {"M": "mamba", "A": "attention"}
+        reps = self.num_layers // len(self.layer_pattern)
+        return tuple(kinds[c] for c in self.layer_pattern * reps)
 
     @property
     def rwkv_heads(self) -> int:
@@ -157,6 +181,13 @@ class ArchConfig:
     def _moe_params_active(self) -> int:
         return self.experts_per_token * self._mlp_params() + self.d_model * self.num_experts
 
+    def _held_moe_params(self) -> int:
+        """moe_hybrid's expert layer: the held experts ([gate|up] in, down
+        out), the router over every expert, the shared expert."""
+        return (3 * self.d_model * self.d_ff * self.held_experts
+                + self.d_model * self.num_experts
+                + 3 * self.d_model * self.shared_expert_ff)
+
     def _mamba_params(self) -> int:
         di, ns, nh = self.d_inner, self.ssm_state, self.ssm_heads
         p = self.d_model * (2 * di + 2 * ns + nh)  # in_proj (z,x,B,C,dt)
@@ -182,6 +213,11 @@ class ArchConfig:
             return self._rwkv_params() + norms
         if self.family == "hybrid":
             return self._mamba_params() + self.d_model  # one pre-norm
+        if self.family == "moe_hybrid":  # mixers averaged over the pattern
+            kinds = self.layer_types
+            mixers = (kinds.count("mamba") * self._mamba_params()
+                      + kinds.count("attention") * self._attn_params())
+            return mixers // len(kinds) + self._held_moe_params() + norms
         mlp = self._mlp_params()
         if self.num_experts:
             mlp = self._moe_params_active() if active else self._moe_params_total()
@@ -191,7 +227,14 @@ class ArchConfig:
         emb = self.padded_vocab() * self.d_model
         head = emb if not self.tie_embeddings else 0
         total = emb + head + self.d_model  # + final norm
-        total += self.num_layers * self.layer_params(active=active)
+        if self.family == "moe_hybrid":
+            kinds = self.layer_types
+            total += (kinds.count("mamba") * self._mamba_params()
+                      + kinds.count("attention") * self._attn_params()
+                      + self.num_layers * (self._held_moe_params()
+                                           + 2 * self.d_model))
+        else:
+            total += self.num_layers * self.layer_params(active=active)
         if self.family == "hybrid" and self.attn_every:
             total += self._attn_params() + self.d_model  # shared attn + ln
         if self.is_encdec:
@@ -215,7 +258,7 @@ def cell_supported(cfg: ArchConfig, shape: ShapeSpec) -> tuple[bool, str]:
     """Whether (arch, shape) is a live dry-run cell; reason when skipped."""
     if shape.name == "long_500k":
         subq = (
-            cfg.family in ("ssm", "hybrid")
+            cfg.family in ("ssm", "hybrid", "moe_hybrid")
             or (cfg.sliding_window and cfg.sliding_window < shape.seq_len)
         )
         if not subq:
@@ -292,6 +335,9 @@ def reduced(cfg: ArchConfig) -> ArchConfig:
         encoder_layers=min(cfg.encoder_layers, 2),
         num_experts=min(cfg.num_experts, 4) if cfg.num_experts else 0,
         experts_per_token=min(cfg.experts_per_token, 2) if cfg.num_experts else 0,
+        experts_held=min(cfg.experts_held, 4),
+        shared_expert_ff=256 if cfg.shared_expert_ff else 0,
+        layer_pattern="MA" if cfg.layer_pattern else "",
     )
     return replace(cfg, **changes)
 

@@ -101,7 +101,7 @@ def attention(
     """Self- (kv_x=None) or cross-attention over full sequences."""
     b, s, _ = x.shape
     hd = cfg.resolved_head_dim
-    scale = hd ** -0.5
+    scale = cfg.attention_multiplier or hd ** -0.5
     kv_src = x if kv_x is None else kv_x
     t = kv_src.shape[1]
 
@@ -230,7 +230,7 @@ def decode_attention(
     """
     b = x.shape[0]
     hd = cfg.resolved_head_dim
-    scale = hd ** -0.5
+    scale = cfg.attention_multiplier or hd ** -0.5
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
 
     q = _project_q(cfg, p, x)

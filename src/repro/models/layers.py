@@ -87,6 +87,8 @@ def embedding_defs(cfg: ArchConfig) -> dict:
 
 def embed_tokens(cfg: ArchConfig, p: dict, tokens: jax.Array) -> jax.Array:
     out = jnp.take(p["embed"], tokens, axis=0)
+    if cfg.embedding_multiplier != 1.0:
+        out = out * cfg.embedding_multiplier
     return shard_act(out, ("batch", "seq", "embed"), essential=True)
 
 
@@ -99,6 +101,8 @@ def lm_logits(cfg: ArchConfig, p: dict, x: jax.Array) -> jax.Array:
         logits = x @ p["embed"].T
     else:
         logits = x @ p["unembed"]
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     return shard_act(logits, ("batch", "seq_inner", "act_vocab"), essential=True)
 
 

@@ -1,4 +1,5 @@
-"""Mixture-of-Experts: top-k router + capacity-based dispatch.
+"""Mixture-of-Experts: top-k router + capacity-based dispatch, and the
+dropless grouped layer over the experts one chip holds (``held_experts``).
 
 Dispatch is *row-local* (per batch row): each sequence dispatches its own
 tokens into (E, C) expert slots via an argsort over that row only, so the
@@ -95,3 +96,87 @@ def moe_apply(cfg: ArchConfig, p: dict, x: jax.Array) -> tuple[jax.Array, jax.Ar
     per_choice = per_choice * flat_w[..., None].astype(per_choice.dtype)
     combined = per_choice.reshape(b, s, k, d).sum(axis=2)
     return shard_act(combined, ("batch", "seq", "embed")), aux
+
+
+# ---------------------------------------------------------------------------
+# Held experts: dropless, grouped (moe_hybrid)
+# ---------------------------------------------------------------------------
+
+
+def held_moe_defs(cfg: ArchConfig) -> dict:
+    """The router over all ``num_experts``, and the weights of the
+    ``held_experts`` this chip holds: ``w_in`` gives [gate | up], ``w_out``
+    the down projection."""
+    d, f, e, eh = cfg.d_model, cfg.d_ff, cfg.num_experts, cfg.held_experts
+    return {
+        "router": PDef((d, e), ("fsdp", "act_experts"), dtype=jnp.float32),
+        "w_in": PDef((eh, d, 2 * f), ("experts", "fsdp", "expert_ffn")),
+        "w_out": PDef((eh, f, d), ("experts", "expert_ffn", "fsdp")),
+    }
+
+
+def shared_expert_defs(cfg: ArchConfig) -> dict:
+    d, f = cfg.d_model, cfg.shared_expert_ff
+    return {"w_in": PDef((d, 2 * f), ("fsdp", "ffn")),
+            "w_out": PDef((f, d), ("ffn", "fsdp"))}
+
+
+def _glu(h: jax.Array, f: int) -> jax.Array:
+    """[a | b] -> silu(a) * b."""
+    return jax.nn.silu(h[..., :f]) * h[..., f:]
+
+
+def shared_expert(cfg: ArchConfig, p: dict, x: jax.Array) -> jax.Array:
+    """The expert every token uses: x (..., D) -> (..., D)."""
+    return _glu(x @ p["w_in"], cfg.shared_expert_ff) @ p["w_out"]
+
+
+def held_experts(cfg: ArchConfig, p: dict, x: jax.Array, layer=0,
+                 first: int = 0) -> tuple[jax.Array, jax.Array]:
+    """Layer ``layer``'s part of the routed experts' output that this chip
+    holds, for every token.
+
+    ``p`` holds every layer's expert layer, stacked: ``router`` (layers, D,
+    num_experts), ``w_in`` (layers, held, D, 2F), ``w_out`` (layers, held,
+    F, D). x: (..., D). The router scores all ``num_experts`` and each
+    token keeps its top ``experts_per_token``, weighted by a softmax over
+    the chosen logits (``route``). The chip holds experts ``first`` to
+    ``first + held_experts - 1`` (its block in expert parallelism) and adds
+    only their part; the other experts' part is their chips'. Dropless:
+    every choice that lands on a held expert is computed. The choices are
+    sorted by expert, so that each held expert's rows are contiguous, and
+    one grouped matmul (``lax.ragged_dot``) per projection runs over them;
+    the choices of experts held elsewhere sort last and fall outside every
+    group. The grouped matmul takes the experts of all layers as its groups
+    and this layer's rows in this layer's groups (the others are empty): so
+    it reads the layer's weights in place, where a slice of the stack
+    would be copied for it.
+
+    Returns ``(y (..., D), hits (...))``: ``hits`` counts each token's
+    choices that landed on a held expert.
+    """
+    lead, d = x.shape[:-1], x.shape[-1]
+    k, eh, f = cfg.experts_per_token, cfg.held_experts, cfg.d_ff
+    layers = p["w_in"].shape[0]
+    xt = x.reshape(-1, d)
+    router = jax.lax.dynamic_index_in_dim(p["router"], layer, 0, False)
+    weights, ids, _ = route(cfg, {"router": router}, xt[None])
+    local = ids[0] - first  # (n, k)
+    held = (local >= 0) & (local < eh)
+    group = jnp.where(held, local, eh).reshape(-1)
+    order = jnp.argsort(group)  # stable: a token's choices keep their order
+    sizes = jax.lax.dynamic_update_slice(
+        jnp.zeros((layers * eh,), jnp.int32),
+        jnp.sum(jax.nn.one_hot(group, eh, dtype=jnp.int32), axis=0),
+        (layer * eh,))
+    rows = xt[order // k]  # (n * k, D), grouped by held expert
+    h = jax.lax.ragged_dot(rows, p["w_in"].reshape((layers * eh,)
+                                                   + p["w_in"].shape[2:]),
+                           sizes)
+    y = jax.lax.ragged_dot(_glu(h, f), p["w_out"].reshape(
+        (layers * eh,) + p["w_out"].shape[2:]), sizes)
+    y = y[jnp.argsort(order)].reshape(-1, k, d)  # back to (token, choice)
+    gate = jnp.where(held, weights[0], 0).astype(jnp.float32)
+    out = jnp.einsum("nk,nkd->nd", gate, y.astype(jnp.float32))
+    return (out.astype(x.dtype).reshape(lead + (d,)),
+            jnp.sum(held, axis=-1, dtype=jnp.int32).reshape(lead))

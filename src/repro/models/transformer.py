@@ -1,5 +1,7 @@
-"""Model assembly: decoder-only / MoE / hybrid(Mamba2+shared-attn) / RWKV /
-encoder-decoder / VLM — one scan-over-layers LM with per-family blocks.
+"""Model assembly: decoder-only / MoE / hybrid(Mamba2+shared-attn) /
+moe_hybrid (Mamba2 and attention layers, each followed by held experts) /
+RWKV / encoder-decoder / VLM — one scan-over-layers LM with per-family
+blocks.
 
 Public surface:
     model_defs(cfg)                  -> PDef tree (single source of truth)
@@ -77,6 +79,18 @@ def _decoder_xattn_layer_defs(cfg: ArchConfig) -> dict:
     return defs
 
 
+def _moe_hybrid_layer_defs(cfg: ArchConfig) -> dict:
+    """What every moe_hybrid layer has besides its mixer: the norm before
+    the mixer, the norm before the experts, the held experts and the shared
+    expert."""
+    return {
+        "ln_in": L.rms_norm_defs(cfg.d_model),
+        "ln_post": L.rms_norm_defs(cfg.d_model),
+        "moe": moe_mod.held_moe_defs(cfg),
+        "shared": moe_mod.shared_expert_defs(cfg),
+    }
+
+
 def hybrid_groups(cfg: ArchConfig) -> tuple[int, int]:
     """(num_groups, tail) — zamba: shared attn block heads each group."""
     g = cfg.attn_every or cfg.num_layers
@@ -99,6 +113,12 @@ def model_defs(cfg: ArchConfig) -> dict:
             "ln": L.rms_norm_defs(cfg.d_model),
             "attn": attn.attention_defs(cfg),
         }
+    elif cfg.family == "moe_hybrid":
+        kinds = cfg.layer_types
+        defs["mamba"] = stack_defs(ssm_mod.mamba_defs(cfg), kinds.count("mamba"))
+        defs["attn"] = stack_defs(attn.attention_defs(cfg),
+                                  kinds.count("attention"))
+        defs["layers"] = stack_defs(_moe_hybrid_layer_defs(cfg), cfg.num_layers)
     elif cfg.is_encdec:
         defs["encoder"] = stack_defs(_encoder_layer_defs(cfg), cfg.encoder_layers)
         defs["enc_norm"] = L.rms_norm_defs(cfg.d_model)
@@ -174,6 +194,53 @@ def _hybrid_group_block(cfg: ArchConfig, p_group: dict, shared: dict,
 def _mamba_block(cfg: ArchConfig, p: dict, x: jax.Array, *, mode: str):
     return _residual(x + ssm_mod.mamba_apply(
         cfg, p["mamba"], L.rms_norm(x, p["ln"], cfg.norm_eps), mode=mode))
+
+
+def _moe_hybrid_layers(cfg: ArchConfig, p):
+    """The layers of period ``p`` (an index, traced in a scan), in order:
+    ``(kind, layer, i)`` with ``kind`` "M" or "A" and ``i`` the layer's
+    index in its mixer's stack."""
+    pattern = cfg.layer_pattern
+    seen = {"M": 0, "A": 0}
+    for j, kind in enumerate(pattern):
+        yield (kind, p * len(pattern) + j,
+               p * pattern.count(kind) + seen[kind])
+        seen[kind] += 1
+
+
+def _moe_hybrid_experts(cfg: ArchConfig, params: dict, x: jax.Array, l):
+    """Layer ``l``'s expert layer: the held experts' part plus the shared
+    expert. The held experts' weights stay in their stack over all layers,
+    which the grouped matmul reads in place (``moe.held_experts``).
+    Returns ``(y, hits)`` as ``moe.held_experts``."""
+    with jax.named_scope("moe"):
+        y, hits = moe_mod.held_experts(cfg, params["layers"]["moe"], x,
+                                       layer=l)
+        shared = _layer(params["layers"]["shared"], l)
+        return y + moe_mod.shared_expert(cfg, shared, x), hits
+
+
+def _moe_hybrid_period(cfg: ArchConfig, params: dict, x: jax.Array, p, *,
+                       mode: str) -> jax.Array:
+    """Period ``p`` of moe_hybrid layers over full sequences; each layer's
+    weights are read from their stacks at its index."""
+    rm, eps = cfg.residual_multiplier, cfg.norm_eps
+    norms = params["layers"]
+    for kind, l, i in _moe_hybrid_layers(cfg, p):
+        h = L.rms_norm(x, _layer(norms["ln_in"], l), eps)
+        if kind == "M":
+            with jax.named_scope("mamba"):
+                y = ssm_mod.mamba_apply(cfg, _layer(params["mamba"], i), h,
+                                        mode=mode)
+        else:
+            with jax.named_scope("attn"):
+                y = attn.attention(cfg, _layer(params["attn"], i), h,
+                                   causal=True, rope=cfg.rope, mode=mode)
+        x = _residual(x + rm * y)
+        h = L.rms_norm(x, _layer(norms["ln_post"], l), eps)
+        y, _ = _moe_hybrid_experts(cfg, params, h, l)
+        x = _residual(x + rm * y)
+    return x
 
 
 def _encoder_block(cfg: ArchConfig, p: dict, x: jax.Array, *, mode: str):
@@ -300,6 +367,17 @@ def forward(cfg: ArchConfig, params: dict, batch: dict, *, mode: str = "exec",
                                   carry), None
 
             x, _ = jax.lax.scan(tbody, x, params["tail"])
+    elif cfg.family == "moe_hybrid":
+        block = _maybe_remat(
+            lambda p, x: _moe_hybrid_period(cfg, params, x, p, mode=mode),
+            remat)
+
+        def body(carry, p):
+            return block(p, carry), None
+
+        periods = cfg.num_layers // len(cfg.layer_pattern)
+        x, _ = jax.lax.scan(body, x, jnp.arange(periods))
+        aux = jnp.zeros((), jnp.float32)
     elif cfg.is_encdec:
         memory = _encode(cfg, params, batch, mode=mode, remat=remat)
         ldefs = _decoder_xattn_layer_defs(cfg)
@@ -347,6 +425,12 @@ def forward_loss(cfg: ArchConfig, params: dict, batch: dict, *,
 # ---------------------------------------------------------------------------
 
 
+def _stack(tree, n: int):
+    """``tree``'s leaves repeated over a new leading axis of ``n`` layers."""
+    return jax.tree.map(lambda v: jnp.broadcast_to(v[None], (n,) + v.shape),
+                        tree)
+
+
 def init_decode_state(cfg: ArchConfig, batch: int, cache_len: int) -> dict:
     """Zeroed decode state for ``batch`` slots. Self-attention caches are
     stacked ``(layers, batch, cache_len, kv_heads, head_dim)`` bfloat16
@@ -354,42 +438,51 @@ def init_decode_state(cfg: ArchConfig, batch: int, cache_len: int) -> dict:
     ``decode_step`` writes one row a slot into them in place: a one-hot
     select in ``decode_attention``, and the layer loop's write of the slice
     back at its index (``_scan_layers_in_place``); with the state donated,
-    as ``ServingEngine`` does, no copy of the cache is made."""
+    as ``ServingEngine`` does, no copy of the cache is made.
+
+    moe_hybrid keeps two stacks side by side, ``mamba`` (conv and SSM state
+    of each Mamba2 layer) and ``attn`` (the KV cache of each attention
+    layer), both carried in place by the layer loop, and ``expert_hits``
+    ``(layers, batch)``: the last step's choices of each slot that landed on
+    a held expert, per layer (the engine's ``expert_assignments``). Its KV
+    cache is head-major, ``(layers, batch, kv_heads, cache_len,
+    head_dim)``: with 128-wide heads the compiler keeps the
+    ``(batch, len, kv_heads, head_dim)`` order row-major, and the attention
+    dots then copy each layer's slice into head-major order and back."""
     # "pos" is a (batch,) vector: every slot carries its OWN position stream
     # so a serving slot can be reset (reset_decode_slots) and re-admitted
     # mid-stream without aliasing cache positions across requests. Uniform
     # values reproduce the legacy single-stream behavior exactly.
     state: dict[str, Any] = {"pos": jnp.zeros((batch,), jnp.int32)}
     if cfg.family == "ssm":
-        per = rwkv_mod.init_rwkv_state(cfg, batch)
-        state["rwkv"] = jax.tree.map(
-            lambda v: jnp.broadcast_to(v[None], (cfg.num_layers,) + v.shape),
-            per)
+        state["rwkv"] = _stack(rwkv_mod.init_rwkv_state(cfg, batch),
+                               cfg.num_layers)
     elif cfg.family == "hybrid":
         ng, tail = hybrid_groups(cfg)
         m = ssm_mod.init_ssm_state(cfg, batch)
-
-        def rep(v, n):
-            return jnp.broadcast_to(v[None], (n,) + v.shape)
-
-        state["mamba"] = jax.tree.map(lambda v: rep(v, ng * cfg.attn_every), m)
+        state["mamba"] = _stack(m, ng * cfg.attn_every)
         if tail:
-            state["mamba_tail"] = jax.tree.map(lambda v: rep(v, tail), m)
+            state["mamba_tail"] = _stack(m, tail)
+        state["attn"] = _stack(attn.init_kv_cache(cfg, batch, cache_len), ng)
+    elif cfg.family == "moe_hybrid":
+        kinds = cfg.layer_types
+        state["mamba"] = _stack(ssm_mod.init_ssm_state(cfg, batch),
+                                kinds.count("mamba"))
         kv = attn.init_kv_cache(cfg, batch, cache_len)
-        state["attn"] = jax.tree.map(lambda v: rep(v, ng), kv)
+        state["attn"] = _stack(jax.tree.map(lambda c: jnp.swapaxes(c, 1, 2),
+                                            kv), kinds.count("attention"))
+        state["expert_hits"] = jnp.zeros((cfg.num_layers, batch), jnp.int32)
     elif cfg.is_encdec:
-        kv = attn.init_kv_cache(cfg, batch, cache_len)
-        state["self"] = jax.tree.map(
-            lambda v: jnp.broadcast_to(v[None], (cfg.num_layers,) + v.shape), kv)
+        state["self"] = _stack(attn.init_kv_cache(cfg, batch, cache_len),
+                               cfg.num_layers)
         hd = cfg.resolved_head_dim
         state["cross_k"] = jnp.zeros(
             (cfg.num_layers, batch, cache_len, cfg.num_kv_heads, hd), jnp.bfloat16)
         state["cross_v"] = jnp.zeros_like(state["cross_k"])
     else:
-        kv = attn.init_kv_cache(cfg, batch, cache_len,
-                                window=cfg.sliding_window)
-        state["kv"] = jax.tree.map(
-            lambda v: jnp.broadcast_to(v[None], (cfg.num_layers,) + v.shape), kv)
+        state["kv"] = _stack(attn.init_kv_cache(cfg, batch, cache_len,
+                                                window=cfg.sliding_window),
+                             cfg.num_layers)
     return state
 
 
@@ -410,6 +503,12 @@ def decode_state_logical_axes(cfg: ArchConfig, state: dict) -> dict:
         if "mamba_tail" in state:
             out["mamba_tail"] = m_axes
         out["attn"] = {"k": kv_axes, "v": kv_axes}
+    elif cfg.family == "moe_hybrid":
+        out["mamba"] = {"ssm": ("layers", "batch", "ssm_heads", None, None),
+                        "conv": ("layers", "batch", None, "ssm_inner")}
+        head_major = ("layers", "kv_batch", "act_kv_heads", "kv_seq", None)
+        out["attn"] = {"k": head_major, "v": head_major}
+        out["expert_hits"] = ("layers", "batch")
     elif cfg.is_encdec:
         out["self"] = {"k": kv_axes, "v": kv_axes}
         out["cross_k"] = kv_axes
@@ -435,7 +534,11 @@ def reset_decode_slots(cfg: ArchConfig, state: dict, reset_mask) -> dict:
     Recurrent families (RWKV / Mamba / hybrid) carry history densely in
     their state, so those leaves ARE re-initialized under the mask; the
     per-request encoder memory of enc-dec models is cleared for the same
-    reason.
+    reason. moe_hybrid is the exception: its ``decode_step`` reads the Mamba
+    state of a slot at position 0 as fresh, inside the state update, so the
+    reset is the position alone. Rewriting the stack here would take two
+    eager copies of it (the fresh stack and the selected one), which do not
+    fit beside the model on one chip.
     """
     reset = jnp.asarray(reset_mask, bool)
     batch = reset.shape[0]
@@ -448,22 +551,15 @@ def reset_decode_slots(cfg: ArchConfig, state: dict, reset_mask) -> dict:
     new_state = dict(state)
     new_state["pos"] = jnp.where(reset, 0, state["pos"])
     if cfg.family == "ssm":
-        per = rwkv_mod.init_rwkv_state(cfg, batch)
-        fresh = jax.tree.map(
-            lambda v: jnp.broadcast_to(v[None], (cfg.num_layers,) + v.shape),
-            per)
+        fresh = _stack(rwkv_mod.init_rwkv_state(cfg, batch), cfg.num_layers)
         new_state["rwkv"] = jax.tree.map(sel, state["rwkv"], fresh)
     elif cfg.family == "hybrid":
         ng, tail = hybrid_groups(cfg)
         m0 = ssm_mod.init_ssm_state(cfg, batch)
-
-        def rep(v, n):
-            return jnp.broadcast_to(v[None], (n,) + v.shape)
-
-        fresh = jax.tree.map(lambda v: rep(v, ng * cfg.attn_every), m0)
+        fresh = _stack(m0, ng * cfg.attn_every)
         new_state["mamba"] = jax.tree.map(sel, state["mamba"], fresh)
         if "mamba_tail" in state:
-            fresh_t = jax.tree.map(lambda v: rep(v, tail), m0)
+            fresh_t = _stack(m0, tail)
             new_state["mamba_tail"] = jax.tree.map(sel, state["mamba_tail"],
                                                    fresh_t)
     elif cfg.is_encdec:
@@ -474,16 +570,24 @@ def reset_decode_slots(cfg: ArchConfig, state: dict, reset_mask) -> dict:
     return new_state
 
 
+def decode_state_cache_axis(cfg: ArchConfig) -> int:
+    """Axis of the cache length in the stacked leaves that
+    :func:`decode_state_cache_keys` names: 2, or 3 in moe_hybrid's
+    head-major KV cache (see :func:`init_decode_state`)."""
+    return 3 if cfg.family == "moe_hybrid" else 2
+
+
 def decode_state_cache_keys(cfg: ArchConfig) -> tuple[str, ...]:
     """State keys whose leaves carry the **cache length** axis (``cache_len``
     at init; axis 2 of the stacked ``(layers, batch, len, ...)`` leaf, axis 1
-    after :func:`extract_decode_slot` drops the batch axis). These are the
+    after :func:`extract_decode_slot` drops the batch axis; one further in
+    moe_hybrid, :func:`decode_state_cache_axis`). These are the
     leaves mid-flight migration must pad/truncate when source and target
     engines disagree on ``max_len``; recurrent leaves (RWKV/Mamba) are
     length-free and move unchanged."""
     if cfg.family == "ssm":
         return ()
-    if cfg.family == "hybrid":
+    if cfg.family in ("hybrid", "moe_hybrid"):
         return ("attn",)
     if cfg.is_encdec:
         return ("self", "cross_k", "cross_v")
@@ -531,29 +635,99 @@ def restore_decode_slot(cfg: ArchConfig, state: dict, slot: int,
     return new_state
 
 
-def _scan_layers_in_place(body, x, xs, cache):
-    """``lax.scan`` over stacked layers with the stacked self-attention cache
-    in the carry, not in ``xs``/``ys``.
+def _layer(stack, i):
+    """Slice ``i`` of every leaf of a stacked state tree."""
+    return jax.tree.map(
+        lambda c: jax.lax.dynamic_index_in_dim(c, i, 0, keepdims=False), stack)
 
-    ``body(x, xs_l, cache_l) -> (x, cache_l, ys_l)`` sees layer ``l``'s slice
-    of every cache leaf; the slice it returns is written back at index ``l``
-    of the carried stack. The stack therefore never passes through a fresh
-    output stack, and under donation the input buffer is the output buffer:
-    no whole-cache copy. Returns ``(x, cache, ys)``.
+
+def _put_layer(stack, new, i):
+    """``stack`` with slice ``i`` of every leaf replaced by ``new``'s."""
+    return jax.tree.map(
+        lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, i, 0),
+        stack, new)
+
+
+def _scan_in_place(body, x, xs, state, length=None):
+    """``lax.scan`` over stacked layers, or periods of layers, with the
+    stacked decode state in the carry, not in ``xs``/``ys``.
+
+    ``body(x, xs_l, state, l) -> (x, state, ys_l)`` reads the slices of
+    step ``l`` that it needs with :func:`_layer` and writes them back with
+    :func:`_put_layer`. The stacks therefore never pass through a fresh
+    output stack, and under donation the input buffers are the output
+    buffers: no whole-state copy. ``length`` is the number of steps where
+    ``xs`` is None. Returns ``(x, state, ys)``.
     """
     def step(carry, xs_l):
-        x, cache, l = carry
-        cache_l = jax.tree.map(
-            lambda c: jax.lax.dynamic_index_in_dim(c, l, 0, keepdims=False),
-            cache)
-        x, cache_l, ys = body(x, xs_l, cache_l)
-        cache = jax.tree.map(
-            lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, l, 0),
-            cache, cache_l)
-        return (x, cache, l + 1), ys
+        x, state, l = carry
+        x, state, ys = body(x, xs_l, state, l)
+        return (x, state, l + 1), ys
 
-    (x, cache, _), ys = jax.lax.scan(step, (x, cache, jnp.int32(0)), xs)
-    return x, cache, ys
+    (x, state, _), ys = jax.lax.scan(step, (x, state, jnp.int32(0)), xs,
+                                     length=length)
+    return x, state, ys
+
+
+def _scan_layers_in_place(body, x, xs, cache):
+    """:func:`_scan_in_place` with one slice of the stacked self-attention
+    cache a layer: ``body(x, xs_l, cache_l) -> (x, cache_l, ys_l)`` sees
+    layer ``l``'s slice of every cache leaf, and the slice it returns is
+    written back at index ``l``. Returns ``(x, cache, ys)``."""
+    def general(x, xs_l, cache, l):
+        x, cache_l, ys = body(x, xs_l, _layer(cache, l))
+        return x, _put_layer(cache, cache_l, l), ys
+
+    return _scan_in_place(general, x, xs, cache)
+
+
+def _moe_hybrid_decode(cfg: ArchConfig, params: dict, state: dict,
+                       x: jax.Array, pos: jax.Array):
+    """moe_hybrid's layers for one token a slot: one ``lax.scan`` over the
+    periods of the layer pattern, each period's layers unrolled, with the
+    Mamba and KV stacks carried in place and each layer's weights read from
+    their stacks at its index. A slot at position 0 starts its Mamba state
+    afresh here (``reset_decode_slots`` resets the position alone).
+    Returns ``(x, {"mamba", "attn"}, hits (periods, per period, B))``."""
+    rm, eps = cfg.residual_multiplier, cfg.norm_eps
+    norms = params["layers"]
+    fresh = (pos == 0)[:, None, None]
+
+    def period(x, _, st, p):
+        hits = []
+        for kind, l, i in _moe_hybrid_layers(cfg, p):
+            h = L.rms_norm(x, _layer(norms["ln_in"], l), eps)
+            if kind == "M":
+                with jax.named_scope("mamba"):
+                    m_i = _layer(st["mamba"], i)
+                    m_i = {"conv": jnp.where(fresh, 0, m_i["conv"]),
+                           "ssm": jnp.where(fresh[..., None], 0.0,
+                                            m_i["ssm"])}
+                    y, new = ssm_mod.mamba_decode_step(
+                        cfg, _layer(params["mamba"], i), h, m_i)
+                    new = jax.tree.map(lambda n, o: n.astype(o.dtype), new,
+                                       m_i)
+                    st = {**st, "mamba": _put_layer(st["mamba"], new, i)}
+            else:
+                with jax.named_scope("attn"):
+                    # the head-major slice seen as (B, T, K, hd): a view
+                    kv_i = jax.tree.map(lambda c: jnp.swapaxes(c, 1, 2),
+                                        _layer(st["attn"], i))
+                    y, kv_i = attn.decode_attention(
+                        cfg, _layer(params["attn"], i), h, kv_i, pos,
+                        rope=cfg.rope)
+                    kv_i = jax.tree.map(lambda c: jnp.swapaxes(c, 1, 2), kv_i)
+                    st = {**st, "attn": _put_layer(st["attn"], kv_i, i)}
+            x = x + rm * y
+            h = L.rms_norm(x, _layer(norms["ln_post"], l), eps)
+            y, hit = _moe_hybrid_experts(cfg, params, h, l)
+            x = x + rm * y
+            hits.append(hit[:, 0])
+        return x, st, jnp.stack(hits)
+
+    return _scan_in_place(
+        period, x, None, {"mamba": state["mamba"], "attn": state["attn"]},
+        length=cfg.num_layers // len(cfg.layer_pattern))
 
 
 def decode_step(cfg: ArchConfig, params: dict, state: dict, tokens: jax.Array
@@ -624,6 +798,10 @@ def decode_step(cfg: ArchConfig, params: dict, state: dict, tokens: jax.Array
             x, mt_new = jax.lax.scan(tbody, x,
                                      (params["tail"], state["mamba_tail"]))
             new_state["mamba_tail"] = mt_new
+    elif cfg.family == "moe_hybrid":
+        x, st, hits = _moe_hybrid_decode(cfg, params, state, x, pos)
+        new_state.update(st)
+        new_state["expert_hits"] = hits.reshape(cfg.num_layers, -1)
     elif cfg.is_encdec:
         def body(x, inp, kv_l):
             p_l, ck, cv = inp

@@ -194,13 +194,14 @@ def _check_geometry(engine: ServingEngine, snap: SlotSnapshot,
     if _digest(snap.manifest) != snap.digest:
         raise MigrationError("snapshot manifest digest mismatch")
     cache_keys = T.decode_state_cache_keys(cfg)
+    axis = T.decode_state_cache_axis(cfg)  # (layers, batch, len, ...)
     for key in cache_keys:
         if key not in snap.leaves:
             raise MigrationError(
                 f"snapshot is missing state key {key!r} — source and "
                 f"target disagree on the model family")
-        src_len = _cache_len(snap.leaves[key], 1)  # batch axis dropped
-        dst_len = _cache_len(state[key], 2)  # (layers, batch, len, ...)
+        src_len = _cache_len(snap.leaves[key], axis - 1)  # batch dropped
+        dst_len = _cache_len(state[key], axis)
         if cfg.sliding_window and src_len != dst_len:
             # a ring buffer's occupancy layout is a function of its length
             # (slot = pos % length): resizing would scramble the ring
@@ -253,10 +254,12 @@ def restore_slot(engine: ServingEngine, snap: SlotSnapshot, *,
     _check_geometry(engine, snap, s["state"])
 
     leaves = dict(snap.leaves)
+    axis = T.decode_state_cache_axis(engine.cfg)
     for key in T.decode_state_cache_keys(engine.cfg):
-        dst_len = _cache_len(s["state"][key], 2)
+        dst_len = _cache_len(s["state"][key], axis)
         leaves[key] = jax.tree.map(
-            lambda v: resize_axis(np.asarray(v), 1, dst_len), leaves[key])
+            lambda v: resize_axis(np.asarray(v), axis - 1, dst_len),
+            leaves[key])
     s["state"] = T.restore_decode_slot(engine.cfg, s["state"], slot,
                                        leaves, snap.pos)
     req = snap.request

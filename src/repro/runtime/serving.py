@@ -156,6 +156,12 @@ class EngineStats:
     # per stream step, the sum over active slots of the cache rows the
     # step's attention sees (cursor + 1): live rows against reserved
     live_row_steps: int = 0
+    # models whose decode state carries ``expert_hits`` (moe_hybrid): the
+    # top-k choices of active slots' tokens that landed on a held expert,
+    # summed over expert layers, and expert-layer evaluations (steps x
+    # expert layers)
+    expert_assignments: int = 0
+    moe_layer_steps: int = 0
 
     @property
     def occupancy(self) -> float:
@@ -610,7 +616,15 @@ class ServingEngine:
         stats.slot_steps += self.slots
         stats.active_slot_steps += sum(r is not None for r in slot_req)
         with span(stats, "pull", k):
-            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            hits = s["state"].get("expert_hits")
+            if hits is None:
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            else:  # one transfer: the tokens and the step's expert choices
+                nxt, hits = jax.device_get((jnp.argmax(logits, axis=-1),
+                                            hits))
+                live = [r is not None for r in slot_req]
+                stats.expert_assignments += int(hits[:, live].sum())
+                stats.moe_layer_steps += hits.shape[0]
         with span(stats, "emit", k):
             done: list[Request] = []
             now = time.perf_counter()

@@ -84,7 +84,7 @@ def test_param_counts_match_config_model(arch, key):
 def test_cell_support_matrix():
     live = [(a, s) for a in ALL_ARCHS for s in SHAPES
             if cell_supported(get_config(a), SHAPES[s])[0]]
-    assert len(live) == 33  # 10*4 - 7 principled long_500k skips
+    assert len(live) == 37  # 11*4 - 7 principled long_500k skips
     skipped = [(a, s) for a in ALL_ARCHS for s in SHAPES
                if not cell_supported(get_config(a), SHAPES[s])[0]]
     assert all(s == "long_500k" for _, s in skipped)

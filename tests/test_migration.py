@@ -38,6 +38,7 @@ FAMILIES = {
     "hybrid": "zamba2-7b",
     "moe": "mixtral-8x7b",
     "encdec": "seamless-m4t-medium",
+    "moe_hybrid": "granite-4.0-h-small",
 }
 MIXED = ("pod2_v5e", "mxu_dense", "hbm_lp")
 

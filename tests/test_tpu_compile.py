@@ -13,8 +13,11 @@ imports this file.
 """
 import dataclasses
 import functools
+import hashlib
+import json
 import math
 import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -143,3 +146,59 @@ def test_stablelm_decode_step_keeps_kv_cache_in_place(one_chip):
     assert max(n for _, n in sizes) >= layer_slice
     assert [n for op, n in sizes if op == "copy" and n >= layer_slice] == []
     assert compiled.memory_analysis().temp_size_in_bytes < 10**9
+
+
+def test_granite_decode_step_fits_one_chip_with_state_in_place(one_chip):
+    """``granite-h-small-chat``'s decode step (granite-4.0-h-small at its
+    chip's share, 48 slots x 1024, as the benchmark builds it) fits one
+    v5e beside its weights and decode state, and carries both kinds of
+    state in place: no copy as large as one layer's Mamba SSM state or KV
+    slice, and temporaries far below either stack."""
+    path = Path(__file__).resolve().parents[1] / "bench" / "configs"
+    conf = json.loads((path / "granite-4.0-h-small.json").read_text())
+    cfg = dataclasses.replace(get_config(conf["arch"]), **conf["overrides"])
+    slots, max_len = 48, 1024
+    compiled = _compile_decode_step(cfg, slots, max_len, one_chip)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < V5E_HBM_BYTES
+    assert mem.temp_size_in_bytes < 10**9
+    kv_slice = slots * max_len * cfg.num_kv_heads * cfg.resolved_head_dim
+    ssm_slice = slots * cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state
+    sizes = _instruction_sizes(compiled.as_text())
+    assert max(n for _, n in sizes) >= max(kv_slice, ssm_slice)
+    assert [n for op, n in sizes
+            if op == "copy" and n >= min(kv_slice, ssm_slice)] == []
+
+
+# sha256 of the lowered decode step (no source locations) of each existing
+# cell's model, as the parent commit of the moe_hybrid family lowers it for
+# one described v5e: adding the family leaves both programs as they were.
+# A change of JAX changes the lowering; recompute them then.
+PARENT_DECODE_DIGESTS = {
+    "stablelm-chat":
+        "9107e1e55b897b8fba34335c86e948f94ea628c85768b7635e968e2e748a088c",
+    "rwkv6-chat":
+        "0b030b1f81370de6637d4296e7e603061fee28f9ff41d916bdb408e29a3429aa",
+}
+
+
+@pytest.mark.parametrize("cell,slots,max_len", [("stablelm-chat", 24, 1024),
+                                                ("rwkv6-chat", 128, 2048)])
+def test_existing_cells_decode_programs_are_unchanged(one_chip, cell, slots,
+                                                      max_len):
+    cfg = {"stablelm-chat": dataclasses.replace(get_config("stablelm-1.6b"),
+                                                qkv_bias=True),
+           "rwkv6-chat": get_config("rwkv6-1.6b")}[cell]
+    params = jax.eval_shape(functools.partial(T.init_params, cfg),
+                            jax.random.PRNGKey(0))
+    state = jax.eval_shape(
+        functools.partial(T.init_decode_state, cfg, slots, max_len))
+    shard = functools.partial(
+        jax.tree.map, lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                                     sharding=one_chip))
+    step = jax.jit(functools.partial(T.decode_step, cfg), donate_argnums=(1,))
+    text = step.lower(shard(params), shard(state),
+                      shard(_spec((slots,), jnp.int32))).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == PARENT_DECODE_DIGESTS[cell]
