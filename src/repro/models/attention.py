@@ -277,3 +277,61 @@ def decode_attention(
     out = out.reshape(b, q.shape[1], cfg.num_heads, hd)
     out = shard_act(out, ("batch", None, "act_heads", None))
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), new_cache
+
+
+def prefill_attention(
+    cfg: ArchConfig,
+    p: dict,
+    x: jax.Array,
+    cache: dict,
+    slot: jax.Array,
+    start: jax.Array,
+    n_valid: jax.Array,
+) -> tuple[jax.Array, dict]:
+    """A chunk of one slot's prompt against that slot's cache rows.
+
+    ``x`` is ``(1, C, D)``: token ``i`` of the chunk sits at position
+    ``start + i``, and the first ``n_valid`` are prompt tokens (the rest
+    padding). ``cache`` is one layer's slice of the stacked cache, ``(B, T,
+    K, hd)``; the slot's ``(T, K, hd)`` slab is read once, the chunk's first
+    ``n_valid`` K and V rows are selected into it at ``start …``, cast to
+    the cache's bfloat16, and the slab is put back at ``slot``: no other
+    slot's rows and no other row of this slot change. Query ``i`` attends
+    over the slab's rows ``<= start + i``, so it reads the prompt's earlier
+    rows exactly as ``decode_attention`` would read them back. Returns
+    ``(out (1, C, D), new_cache)``.
+    """
+    c = x.shape[1]
+    hd = cfg.resolved_head_dim
+    scale = cfg.attention_multiplier or hd ** -0.5
+    length = cache["k"].shape[1]
+    positions = start + jnp.arange(c)
+
+    q = apply_rope(_project_q(cfg, p, x), positions, cfg.rope_theta)
+    k_new, v_new = _project_kv(cfg, p, x)
+    k_new = apply_rope(k_new, positions, cfg.rope_theta)
+    idx = jnp.arange(length)
+    fresh = ((idx >= start) & (idx < start + n_valid))[:, None, None]
+
+    def write(leaf, rows):
+        slab = jax.lax.dynamic_index_in_dim(leaf, slot, 0, keepdims=False)
+        hit = (idx[:, None] - start) == jnp.arange(c)[None, :]
+        placed = jnp.einsum("tc,ckd->tkd", hit.astype(leaf.dtype),
+                            rows[0].astype(leaf.dtype))
+        slab = jnp.where(fresh, placed, slab)
+        return jax.lax.dynamic_update_index_in_dim(leaf, slab, slot, 0), slab
+
+    k_cache, k = write(cache["k"], k_new)
+    v_cache, v = write(cache["v"], v_new)
+
+    kh = k.shape[1]
+    g = cfg.num_heads // kh
+    qg = q.reshape(c, kh, g, hd)
+    scores = jnp.einsum("qkgd,tkd->kgqt", qg, k).astype(jnp.float32) * scale
+    mask = idx[None, :] <= positions[:, None]
+    scores = jnp.where(mask[None, None], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    out = jnp.einsum("kgqt,tkd->qkgd", probs, v).reshape(1, c, cfg.num_heads,
+                                                         hd)
+    return (jnp.einsum("bshk,hkd->bsd", out, p["wo"]),
+            {"k": k_cache, "v": v_cache})

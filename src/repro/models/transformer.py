@@ -10,6 +10,8 @@ Public surface:
     forward(cfg, params, batch)      -> logits                  [prefill]
     init_decode_state(cfg, batch, cache_len) -> state
     decode_step(cfg, params, state, tokens)  -> (logits, state) [serve]
+    prefill_chunk(cfg, params, state, slot, tokens, start, n_valid)
+                                             -> state           [serve]
 """
 from __future__ import annotations
 
@@ -842,3 +844,42 @@ def decode_step(cfg: ArchConfig, params: dict, state: dict, tokens: jax.Array
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = L.lm_logits(cfg, params["embedding"], x)
     return logits[:, 0], new_state
+
+
+def prefill_chunk_supported(cfg: ArchConfig) -> bool:
+    """Whether :func:`prefill_chunk` can feed this model's prompts: a dense
+    decoder with full attention and no experts. Recurrent state (RWKV,
+    Mamba), expert layers, a sliding-window ring and encoder memory have no
+    chunk path; their prompts go through ``decode_step`` a token a step."""
+    return (cfg.family == "dense" and not cfg.sliding_window
+            and not cfg.num_experts)
+
+
+def prefill_chunk(cfg: ArchConfig, params: dict, state: dict, slot, tokens,
+                  start, n_valid) -> dict:
+    """Feed one slot a chunk of its prompt; returns the new state.
+
+    ``tokens`` is ``(C,)`` int32: the first ``n_valid`` are the slot's
+    prompt tokens at positions ``start … start + n_valid - 1``, the rest
+    padding. Every layer's K and V rows of those tokens are written into
+    the slot's rows of the cache (``attention.prefill_attention``), with the
+    stack carried in place by the layer loop, and ``pos[slot]`` becomes
+    ``start + n_valid``: the state one-token feeding through
+    :func:`decode_step` would leave, up to rounding. No other slot's rows or
+    position change. No logits: the last prompt token goes through
+    ``decode_step``, which emits the first output token. Only for models
+    :func:`prefill_chunk_supported` accepts.
+    """
+    x = L.embed_tokens(cfg, params["embedding"], tokens[None])
+
+    def body(x, p_l, kv_l):
+        xn = L.rms_norm(x, p_l["ln1"], cfg.norm_eps)
+        y, kv_new = attn.prefill_attention(cfg, p_l["attn"], xn, kv_l, slot,
+                                           start, n_valid)
+        x = x + y
+        xn = L.rms_norm(x, p_l["ln2"], cfg.norm_eps)
+        return x + L.mlp_apply(cfg, p_l["mlp"], xn), kv_new, None
+
+    _, kv, _ = _scan_layers_in_place(body, x, params["layers"], state["kv"])
+    return {**state, "kv": kv,
+            "pos": state["pos"].at[slot].set(start + n_valid)}

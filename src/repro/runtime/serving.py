@@ -9,9 +9,13 @@ is masked-reset (position back to 0, recurrent state re-initialized) while
 its neighbors keep decoding, so cache positions never alias across the
 requests sharing a slot — exactness is preserved for all architecture
 families, and for any fixed request set the decoded outputs are
-token-identical to the wave scheduler's. Prompts are still fed
-token-by-token ("prefill-as-decode" — exact for every family, incl.
-SSM/hybrid, since the decode step IS the recurrence).
+token-identical to the wave scheduler's. A dense model's prompt is
+prefilled at admission: all but its last token go through
+``transformer.prefill_chunk`` in chunks of ``prefill_len`` tokens, and the
+decode step of the admitting step takes the last one and emits the first
+output token. Every other family feeds its prompt a token a step
+("prefill-as-decode" — exact for SSM/hybrid too, since the decode step IS
+the recurrence).
 
 **Waves** (``scheduler="wave"``): requests are admitted in waves of up to B
 slots sharing one fresh decode state; finished slots idle out until the
@@ -156,6 +160,10 @@ class EngineStats:
     # per stream step, the sum over active slots of the cache rows the
     # step's attention sees (cursor + 1): live rows against reserved
     live_row_steps: int = 0
+    # chunked prefill at admission (models whose prompts prefill_chunk can
+    # feed): chunk programs dispatched, and the prompt tokens they consumed
+    prefill_chunks: int = 0
+    chunked_prompt_tokens: int = 0
     # models whose decode state carries ``expert_hits`` (moe_hybrid): the
     # top-k choices of active slots' tokens that landed on a held expert,
     # summed over expert layers, and expert-layer evaluations (steps x
@@ -209,6 +217,9 @@ class Placement:
 
 
 POWER_STATES = ("awake", "floor", "asleep", "waking")
+
+#: prompt tokens one chunked-prefill program consumes (capped at max_len)
+PREFILL_CHUNK = 256
 
 
 class ServingEngine:
@@ -286,6 +297,15 @@ class ServingEngine:
             return T.decode_step(cfg, params, state, tokens)
 
         self._step = jax.jit(decode_step, donate_argnums=(1,))
+        # chunked prefill (_prefill_prompt): one program shape (slot, start
+        # and n_valid traced), the state donated as in the decode step
+        self.prefill_len = min(PREFILL_CHUNK, max_len)
+
+        def prefill_chunk(params, state, slot, tokens, start, n_valid):
+            return T.prefill_chunk(cfg, params, state, slot, tokens, start,
+                                   n_valid)
+
+        self._prefill = jax.jit(prefill_chunk, donate_argnums=(1,))
 
     def submit(self, req: Request) -> bool:
         """Admit a request; False when rejected (empty prompt, a prompt the
@@ -502,6 +522,36 @@ class ServingEngine:
             self.stats.slo_at_risk += 1
         self.active.append(req)
 
+    def _prefill_prompt(self, state, slot: int, req: Request,
+                        epoch: Mapping[str, Placement]) -> tuple[dict, int]:
+        """Feed ``req.prompt[:-1]`` into ``slot`` through the chunk program
+        (``ceil((L-1)/prefill_len)`` calls dispatched back to back, no host
+        sync) and bill those tokens as prefill under the slot's admission
+        ``epoch``. Returns the state and the slot's cursor, ``L - 1``: the
+        decode step then takes the last prompt token and emits the first
+        output token. Engines whose model has no chunk path get both back
+        unchanged (cursor 0) and feed the prompt a token a step.
+
+        Invariant: at every step boundary a slot's cursor equals its
+        ``pos``, and its cache rows below it hold the prompt as one-token
+        feeding would have written them, so migration, ``live_row_steps``
+        and the finish rules need nothing of their own."""
+        n = len(req.prompt) - 1
+        if n <= 0 or not T.prefill_chunk_supported(self.cfg):
+            return state, 0
+        c = self.prefill_len
+        for start in range(0, n, c):
+            part = req.prompt[start:min(start + c, n)]
+            tokens = np.zeros((c,), np.int32)
+            tokens[:len(part)] = part
+            state = self._prefill(self.params, state, slot,
+                                  jnp.asarray(tokens), start, len(part))
+            self.stats.prefill_chunks += 1
+        self.stats.chunked_prompt_tokens += n
+        self.stats.prefill_tokens += n
+        self.stats.energy_ws += n * self._token_energy("prefill", epoch)
+        return state, n
+
     def _finish(self, req: Request, reason: str) -> None:
         req.done = True
         req.finish_reason = reason
@@ -596,6 +646,9 @@ class ServingEngine:
                 s["state"] = T.reset_decode_slots(self.cfg, s["state"],
                                                   jnp.asarray(mask))
         with span(stats, "feed", k):
+            for i in newly:
+                s["state"], cursors[i] = self._prefill_prompt(
+                    s["state"], i, slot_req[i], slot_epoch[i])
             step_s = 0.0
             tokens = np.zeros((self.slots,), np.int32)
             for i, req in enumerate(slot_req):
@@ -739,8 +792,11 @@ class ServingEngine:
             "cap": [self.max_len] * len(wave),
             "steps": 0,
         }
-        for req in wave:
+        for i, req in enumerate(wave):
             self._admit(req)
+            self._wave["state"], self._wave["cursors"][i] = \
+                self._prefill_prompt(self._wave["state"], i, req,
+                                     self._wave["epoch"][i])
 
     def wave_step(self) -> Optional[list[Request]]:
         """One decode step of the open wave session. Returns the requests

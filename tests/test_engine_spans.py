@@ -79,10 +79,11 @@ def test_reset_only_on_admitting_steps(small_model):
 def test_live_rows_match_a_hand_count(small_model):
     reqs = _requests()
     eng, _ = _serve(*small_model, reqs)
-    # a request of L prompt and n output tokens is stepped at cursors
-    # 0 .. L + n - 2, and the step at cursor c sees c + 1 rows
-    want = sum((len(r.prompt) + len(r.output) - 1)
-               * (len(r.prompt) + len(r.output)) // 2 for r in reqs)
+    # a request of L prompt and n output tokens has its first L - 1 tokens
+    # chunk-fed at admission, is stepped at cursors L - 1 .. L + n - 2, and
+    # the step at cursor c sees c + 1 rows
+    want = sum(sum(range(len(r.prompt), len(r.prompt) + len(r.output)))
+               for r in reqs)
     assert all(r.finish_reason == "max_new_tokens" for r in reqs)
     assert eng.stats.live_row_steps == want
     assert eng.stats.live_row_steps <= eng.stats.slot_steps * eng.max_len

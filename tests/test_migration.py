@@ -224,7 +224,8 @@ def test_snapshot_restore_roundtrip_identity(arch, dst_len, steps):
     cfg, params = _model(arch)
     src = ServingEngine(cfg, params, slots=2, max_len=32, name="src")
     dst = ServingEngine(cfg, params, slots=2, max_len=dst_len, name="dst")
-    rs = [Request(rid=i, prompt=[2 + i, 5, 9], max_new_tokens=4)
+    # 6 new tokens: a chunk-fed dense request holds its slot for 6 steps
+    rs = [Request(rid=i, prompt=[2 + i, 5, 9], max_new_tokens=6)
           for i in range(2)]
     for r in rs:
         src.submit(r)

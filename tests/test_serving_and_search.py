@@ -40,13 +40,15 @@ def test_serving_batched_requests(small_model):
     assert all(len(r.output) == 5 for r in done)
     assert eng.stats.waves == 0  # no waves under slot streams
     assert eng.stats.admissions == 6
-    # each request: 3 prompt-consuming steps (prefill) + 4 more generated
-    # tokens (the last prefill step already emits the first one)
+    # each request: 3 prompt tokens billed as prefill (2 chunk-fed at
+    # admission, the last by the admitting step, which already emits the
+    # first output token) + 4 more generated tokens
     assert eng.stats.prefill_tokens == 18
     assert eng.stats.decode_tokens == 24
-    # 7 steps per request over 4 slots, packed back-to-back: 4 slots serve
-    # {2,2,1,1} requests -> 14 steps, not the wave scheduler's 2 x 7
-    assert eng.stats.steps == 14
+    assert eng.stats.prefill_chunks == 6
+    # 5 steps per request over 4 slots, packed back-to-back: 4 slots serve
+    # {2,2,1,1} requests -> 10 steps
+    assert eng.stats.steps == 10
 
 
 def test_serving_wave_scheduler_still_available(small_model):
@@ -61,7 +63,8 @@ def test_serving_wave_scheduler_still_available(small_model):
     assert eng.stats.waves == 2  # 6 requests over 4 slots
     assert eng.stats.prefill_tokens == 18
     assert eng.stats.decode_tokens == 24
-    assert eng.stats.steps == 14  # both waves run their longest request
+    # both waves run their longest request: 1 admitting step + 4 more
+    assert eng.stats.steps == 10
 
 
 def test_serving_greedy_matches_manual_decode(small_model):

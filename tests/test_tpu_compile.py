@@ -31,6 +31,7 @@ from repro.kernels.himeno.kernel import himeno_jacobi_pallas
 from repro.kernels.rmsnorm.kernel import rms_norm_pallas
 from repro.kernels.wkv.kernel import wkv_pallas
 from repro.models import transformer as T
+from repro.runtime.serving import PREFILL_CHUNK
 
 V5E_HBM_BYTES = 16 * 10**9
 
@@ -104,8 +105,9 @@ def test_wkv_kernel_compiles(one_chip):
     assert _has_kernel(compiled)
 
 
-def _compile_decode_step(cfg, slots: int, max_len: int, sharding):
-    """The decode step as ``ServingEngine`` jits it, state donated."""
+def _engine_args(cfg, slots: int, max_len: int, sharding):
+    """Shapes of the parameters and the decode state, on ``sharding``, and
+    the function that puts further shapes there."""
     params = jax.eval_shape(functools.partial(T.init_params, cfg),
                             jax.random.PRNGKey(0))
     state = jax.eval_shape(
@@ -113,8 +115,14 @@ def _compile_decode_step(cfg, slots: int, max_len: int, sharding):
     shard = functools.partial(
         jax.tree.map, lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
                                                      sharding=sharding))
+    return shard(params), shard(state), shard
+
+
+def _compile_decode_step(cfg, slots: int, max_len: int, sharding):
+    """The decode step as ``ServingEngine`` jits it, state donated."""
+    params, state, shard = _engine_args(cfg, slots, max_len, sharding)
     step = jax.jit(functools.partial(T.decode_step, cfg), donate_argnums=(1,))
-    return step.lower(shard(params), shard(state),
+    return step.lower(params, state,
                       shard(_spec((slots,), jnp.int32))).compile()
 
 
@@ -146,6 +154,36 @@ def test_stablelm_decode_step_keeps_kv_cache_in_place(one_chip):
     assert max(n for _, n in sizes) >= layer_slice
     assert [n for op, n in sizes if op == "copy" and n >= layer_slice] == []
     assert compiled.memory_analysis().temp_size_in_bytes < 10**9
+
+
+def test_stablelm_prefill_chunk_writes_kv_cache_in_place(one_chip):
+    """``stablelm-chat``'s chunked-prefill program (24 slots x 1024, a chunk
+    of 256 prompt tokens, state donated, as ``ServingEngine`` jits it)
+    writes one slot's rows in place: no copy as large as one layer's K
+    slice, temporaries far below the 4.8 GB cache, arguments and
+    temporaries within the chip, and the state in the layouts the decode
+    step leaves and takes, so nothing is relaid out between the two."""
+    cfg = dataclasses.replace(get_config("stablelm-1.6b"), qkv_bias=True)
+    slots, max_len = 24, 1024
+    params, state, shard = _engine_args(cfg, slots, max_len, one_chip)
+    scalar = shard(_spec((), jnp.int32))
+    chunk = jax.jit(functools.partial(T.prefill_chunk, cfg),
+                    donate_argnums=(1,))
+    compiled = chunk.lower(
+        params, state, scalar,
+        shard(_spec((min(PREFILL_CHUNK, max_len),), jnp.int32)), scalar,
+        scalar).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < V5E_HBM_BYTES
+    assert mem.temp_size_in_bytes < 10**9
+    layer_slice = slots * max_len * cfg.num_kv_heads * cfg.resolved_head_dim
+    sizes = _instruction_sizes(compiled.as_text())
+    assert max(n for _, n in sizes) >= layer_slice
+    assert [n for op, n in sizes if op == "copy" and n >= layer_slice] == []
+    decode = _compile_decode_step(cfg, slots, max_len, one_chip)
+    assert compiled.input_formats[0][1] == decode.output_formats[1]
+    assert compiled.output_formats == decode.input_formats[0][1]
 
 
 def test_granite_decode_step_fits_one_chip_with_state_in_place(one_chip):
