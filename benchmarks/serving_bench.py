@@ -1,9 +1,7 @@
-"""Serving benchmark: static vs traffic-adaptive placement (Watt·s / 1k tok)
-and the slot-stream vs wave scheduler comparison.
+"""Serving benchmark: static vs traffic-adaptive placement (Watt·s / 1k tok).
 
-Drives the :class:`ServingEngine` under three traffic scenarios —
-prefill-heavy, decode-heavy, mixed-burst — twice each on the legacy wave
-scheduler (keeping the PR-2/PR-3 trajectory comparable):
+Drives the slot-stream :class:`ServingEngine` under three traffic
+scenarios — prefill-heavy, decode-heavy, mixed-burst — twice each:
 
 * **static**   — the paper-faithful default placement (``Decisions()`` at
   nominal clock on the default mesh) for the whole run.
@@ -12,12 +10,8 @@ scheduler (keeping the PR-2/PR-3 trajectory comparable):
   disk-persisted measurement cache, narrow via the kind-level fleet
   frontier + staged destination selection, reconfigure.
 
-A fourth **ragged-length** scenario pits the slot-stream scheduler against
-the wave scheduler on traffic with wildly mixed prompt/generation lengths —
-the case where wave barriers idle slots. It reports occupancy, steps and
-Watt·s/1k-tokens for both, checks the decoded outputs are token-identical
-(slot streams change scheduling, never tokens), and runs the slot-stream
-engine once more under the step-windowed adaptive controller.
+A fourth **ragged-length** scenario (wildly mixed prompt/generation
+lengths) runs static and under the step-windowed adaptive controller.
 
 Reported metric is modeled Watt·s per 1k processed tokens (the paper's Fig.5
 quantity, normalized to traffic); the adaptive loop must not lose to static
@@ -66,7 +60,7 @@ def _requests(scenario: str):
         for i in range(12):
             reqs.append(Request(rid=i, prompt=[1 + i % 7, 3],
                                 max_new_tokens=12))
-    elif scenario == "mixed_burst":  # alternating wave-sized bursts
+    elif scenario == "mixed_burst":  # alternating slot-sized bursts
         rid = 0
         for burst in range(3):
             long_burst = burst % 2 == 0
@@ -80,7 +74,7 @@ def _requests(scenario: str):
                     reqs.append(Request(rid=rid, prompt=[2 + rid % 5, 4],
                                         max_new_tokens=10))
                 rid += 1
-    elif scenario == "ragged":  # wildly mixed lengths: wave barriers idle
+    elif scenario == "ragged":  # wildly mixed lengths
         for i in range(16):
             plen = 2 + (i * 7) % 23
             reqs.append(Request(rid=i,
@@ -93,37 +87,33 @@ def _requests(scenario: str):
 
 
 def _serve(cfg, params, scenario: str, *, adaptive: bool,
-           scheduler: str = "wave", cache_path: str = CACHE_PATH,
-           collect_outputs: bool = False):
+           cache_path: str = CACHE_PATH):
     from repro.core.ga import GAConfig
     from repro.runtime import (
         PlacementController, ServingEngine, static_placements,
     )
 
-    engine = ServingEngine(cfg, params, slots=SLOTS, max_len=MAX_LEN,
-                           scheduler=scheduler)
+    engine = ServingEngine(cfg, params, slots=SLOTS, max_len=MAX_LEN)
     engine.reconfigure(static_placements(ARCH, MESH_OPTIONS[0]))
     controller = None
     if adaptive:
         controller = PlacementController(
             engine, ARCH, MESH_OPTIONS, cache_path=cache_path,
             ga_config=GAConfig(population=10, generations=8, seed=0),
-            interval_waves=1, interval_steps=12).attach()
+            interval_steps=12).attach()
     for r in _requests(scenario):
         engine.submit(r)
     t0 = time.perf_counter()
     done = engine.run()
     wall = time.perf_counter() - t0
     s = engine.stats
-    record = {
+    return {
         "cache": (cache_stats_json(controller.eval_engine.cache.stats())
                   if controller else cache_stats_json(None)),
-        "scheduler": scheduler,
         "completed": len(done),
         "tokens": s.total_tokens,
         "energy_ws": s.energy_ws,
         "ws_per_1k": s.energy_ws / max(s.total_tokens, 1) * 1e3,
-        "waves": s.waves,
         "steps": s.steps,
         "reconfigurations": s.reconfigurations,
         "occupancy": s.occupancy,
@@ -136,9 +126,6 @@ def _serve(cfg, params, scenario: str, *, adaptive: bool,
                        for k, p in engine.placements.items()},
         "wall_s": wall,
     }
-    if collect_outputs:
-        record["outputs"] = {r.rid: list(r.output) for r in done}
-    return record
 
 
 def run(json_path=None) -> list[tuple]:
@@ -172,49 +159,25 @@ def run(json_path=None) -> list[tuple]:
                  f"adaptive beats static on {wins}/{len(scenarios)} scenarios"
                  f" (Watt·s per 1k tokens)"))
 
-    # ragged-length scenario: slot-stream vs wave scheduler on the same
-    # request set. Occupancy is the win; outputs must be token-identical and
-    # Watt·s/1k no worse. The stream engine then runs once more under the
-    # step-windowed adaptive controller.
-    wave_r = _serve(cfg, params, "ragged", adaptive=False, scheduler="wave",
-                    collect_outputs=True)
-    stream_r = _serve(cfg, params, "ragged", adaptive=False,
-                      scheduler="stream", collect_outputs=True)
-    stream_ad = _serve(cfg, params, "ragged", adaptive=True,
-                       scheduler="stream")
-    identical = wave_r["outputs"] == stream_r["outputs"]
-    occ_gain = stream_r["occupancy"] - wave_r["occupancy"]
-    ws_delta = stream_r["ws_per_1k"] - wave_r["ws_per_1k"]
-    scenario_records["ragged"] = {
-        "wave_static": wave_r, "stream_static": stream_r,
-        "stream_adaptive": stream_ad,
-        "outputs_identical": identical,
-        "occupancy_gain": occ_gain,
-        "ws_per_1k_delta": ws_delta,
-    }
-    rows.append(("serving_ragged_stream_vs_wave", stream_r["wall_s"] * 1e6,
-                 f"occ={wave_r['occupancy']:.2f}->{stream_r['occupancy']:.2f}"
-                 f" steps={wave_r['steps']}->{stream_r['steps']} "
-                 f"ws/1k={wave_r['ws_per_1k']:.1f}->"
-                 f"{stream_r['ws_per_1k']:.1f} identical={identical}"))
-    rows.append(("serving_ragged_adaptive_stream", stream_ad["wall_s"] * 1e6,
-                 f"static={stream_r['ws_per_1k']:.1f}Ws/1k "
-                 f"adaptive={stream_ad['ws_per_1k']:.1f}Ws/1k "
-                 f"occ={stream_ad['occupancy']:.2f} "
-                 f"reconfigs={stream_ad['reconfigurations']}"))
+    # ragged-length scenario: static, then under the step-windowed
+    # adaptive controller
+    static = _serve(cfg, params, "ragged", adaptive=False)
+    adaptive = _serve(cfg, params, "ragged", adaptive=True)
+    scenario_records["ragged"] = {"static": static, "adaptive": adaptive}
+    rows.append(("serving_ragged_adaptive_stream", adaptive["wall_s"] * 1e6,
+                 f"static={static['ws_per_1k']:.1f}Ws/1k "
+                 f"adaptive={adaptive['ws_per_1k']:.1f}Ws/1k "
+                 f"occ={adaptive['occupancy']:.2f} "
+                 f"reconfigs={adaptive['reconfigurations']}"))
 
     # persisted cache: every scenario re-planned from a FRESH cache over the
     # same results file must need zero new measurements (cross-process
     # incrementality, ROADMAP item 3)
     resweep_meas = 0
     t0 = time.perf_counter()
-    for sc in scenarios:
+    for sc in scenarios + ("ragged",):
         again = _serve(cfg, params, sc, adaptive=True)
         resweep_meas += again["new_measurements"]
-    # the step-windowed slot-stream path must be incremental too: its cell
-    # keys are as deterministic as the wave path's
-    again = _serve(cfg, params, "ragged", adaptive=True, scheduler="stream")
-    resweep_meas += again["new_measurements"]
     rows.append(("serving_cache_resweep", (time.perf_counter() - t0) * 1e6,
                  f"new_measurements={resweep_meas} across "
                  f"{len(scenarios) + 1} re-served scenarios "
@@ -223,10 +186,7 @@ def run(json_path=None) -> list[tuple]:
     if json_path:
         # aggregate eval-cache traffic over every adaptive serve in the run
         totals = cache_stats_json(None)
-        adaptive_runs = [rec["adaptive"] for rec in scenario_records.values()
-                         if "adaptive" in rec]
-        adaptive_runs.append(scenario_records["ragged"]["stream_adaptive"])
-        for run_rec in adaptive_runs:
+        for run_rec in (rec["adaptive"] for rec in scenario_records.values()):
             for k in ("lookups", "hits", "cross_cell_hits", "inserts"):
                 totals[k] += run_rec["cache"][k]
         totals["hit_rate"] = (totals["hits"] / totals["lookups"]
@@ -240,9 +200,6 @@ def run(json_path=None) -> list[tuple]:
                 "adaptive_wins": wins,
                 "scenario_count": len(scenarios),
                 "resweep_new_measurements": resweep_meas,
-                "ragged_outputs_identical": identical,
-                "ragged_occupancy_gain": occ_gain,
-                "ragged_ws_per_1k_delta": ws_delta,
             },
             cache=totals))
     return rows

@@ -1,8 +1,8 @@
 """Serving example: batched requests through the slot-stream engine
-(continuous batching with per-slot position streams — the default
-scheduler), across three architecture families (dense, SSM, MoE) with one
-code path. Each engine carries a destination-priced placement, so every
-served request reports which engine and offload destination billed it.
+(continuous batching with per-slot position streams), across three
+architecture families (dense, SSM, MoE) with one code path. Each engine
+carries a destination-priced placement, so every served request reports
+which engine and offload destination billed it.
 
     PYTHONPATH=src python examples/serve_lm.py
 """

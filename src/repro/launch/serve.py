@@ -1,12 +1,9 @@
-"""Serving driver: batched requests through the slot-stream engine — the
-**default scheduler** since PR 4 (``--scheduler wave`` selects the legacy
-wave scheduler, kept for reproducible comparisons only).
+"""Serving driver: batched requests through the slot-stream engine.
 
 ``--adaptive`` attaches the traffic-adaptive placement controller
 (runtime/placement.py): the engine starts on the static paper-faithful
-placement and re-plans from the observed traffic mix — on a step-count
-window under slot streams, between waves under the wave scheduler — through
-the disk-persisted measurement cache under ``results/``.
+placement and re-plans from the observed traffic mix on a step-count window,
+through the disk-persisted measurement cache under ``results/``.
 
 ``--fleet`` serves through the :class:`~repro.runtime.router.FleetRouter`
 instead: one engine per mixed-environment catalog destination
@@ -50,14 +47,12 @@ def serve(arch: str = "llama3.2-3b", *, use_reduced: bool = True,
           num_requests: int = 8, slots: int = 4, max_new_tokens: int = 8,
           max_len: int = 64, adaptive: bool = False,
           cache_path: Optional[str] = "results/eval_cache.jsonl",
-          interval_waves: int = 1, interval_steps: int = 16,
-          scheduler: str = "stream") -> dict:
+          interval_steps: int = 16) -> dict:
     cfg = get_config(arch)
     if use_reduced:
         cfg = reduce_cfg(cfg)
     params = M.init_params(cfg, jax.random.PRNGKey(0))
-    engine = ServingEngine(cfg, params, slots=slots, max_len=max_len,
-                           scheduler=scheduler)
+    engine = ServingEngine(cfg, params, slots=slots, max_len=max_len)
     # modeled production-cell energy rates (full config, not the reduced one
     # actually decoding locally): the Watt·s ledger the search minimizes
     engine.reconfigure(static_placements(arch, DEFAULT_MESH))
@@ -66,7 +61,6 @@ def serve(arch: str = "llama3.2-3b", *, use_reduced: bool = True,
         controller = PlacementController(
             engine, arch, DEFAULT_MESH_OPTIONS, cache_path=cache_path,
             ga_config=GAConfig(population=10, generations=8),
-            interval_waves=interval_waves,
             interval_steps=interval_steps).attach()
     for r in _requests(num_requests, max_new_tokens):
         engine.submit(r)
@@ -81,7 +75,6 @@ def serve(arch: str = "llama3.2-3b", *, use_reduced: bool = True,
         "decode_tokens": toks,
         "wall_s": wall,
         "tokens_per_s": toks / max(wall, 1e-9),
-        "waves": engine.stats.waves,
         "steps": engine.stats.steps,
         "occupancy": engine.stats.occupancy,
         "energy_ws": engine.stats.energy_ws,
@@ -135,7 +128,6 @@ def serve_fleet(arch: str = "llama3.2-3b", *, use_reduced: bool = True,
                 max_new_tokens: int = 8, max_len: int = 64,
                 policy: str = "energy", adaptive: bool = False,
                 cache_path: Optional[str] = "results/eval_cache.jsonl",
-                scheduler: str = "stream",
                 provision_budget_w: Optional[float] = None) -> dict:
     """Serve across the mixed-destination fleet (one engine per catalog
     destination). With ``adaptive``, one shared sweep re-plans every engine
@@ -147,7 +139,7 @@ def serve_fleet(arch: str = "llama3.2-3b", *, use_reduced: bool = True,
         cfg = reduce_cfg(cfg)
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     kwargs = dict(arch=arch, policy=policy, slots=slots, max_len=max_len,
-                  scheduler=scheduler, cache_path=cache_path,
+                  cache_path=cache_path,
                   ga_config=GAConfig(population=10, generations=8))
     if provision_budget_w is not None:
         counts = _provision_counts(arch, provision_budget_w, cache_path)
@@ -193,15 +185,9 @@ def main() -> None:
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new-tokens", type=int, default=8)
     ap.add_argument("--full", action="store_true")
-    ap.add_argument("--scheduler", default="stream",
-                    choices=("stream", "wave"),
-                    help="stream = slot-stream continuous batching (the "
-                         "default scheduler); wave = the legacy wave "
-                         "scheduler, kept for reproducible comparisons")
     ap.add_argument("--adaptive", action="store_true",
                     help="traffic-adaptive placement (observe/sweep/narrow/"
-                         "reconfigure on a step-count window, or between "
-                         "waves under --scheduler wave)")
+                         "reconfigure on a step-count window)")
     ap.add_argument("--fleet", action="store_true",
                     help="serve across the mixed-destination fleet "
                          "(FleetRouter, one engine per catalog destination)")
@@ -222,13 +208,12 @@ def main() -> None:
                           num_requests=args.requests, slots=args.slots,
                           max_new_tokens=args.max_new_tokens,
                           policy=args.policy, adaptive=args.adaptive,
-                          scheduler=args.scheduler,
                           provision_budget_w=args.provision_budget_w)
     else:
         out = serve(args.arch, use_reduced=not args.full,
                     num_requests=args.requests, slots=args.slots,
                     max_new_tokens=args.max_new_tokens,
-                    adaptive=args.adaptive, scheduler=args.scheduler)
+                    adaptive=args.adaptive)
     print(f"served {out['completed']} requests, {out['decode_tokens']} tokens "
           f"in {out['wall_s']:.2f}s ({out['tokens_per_s']:.1f} tok/s, "
           f"{out['steps']} steps, occupancy {out['occupancy']:.2f})")

@@ -19,8 +19,8 @@ Correctness argument (the one ``analysis/concurrency.py`` certifies):
   happens-before edge between a worker's writes and the coordinator's
   reads, and the coordinator's submissions order tick t's writes before
   tick t+1's reads. No engine attribute needs a lock.
-* **Identical per-engine schedules.** A stream engine's life under the
-  executor is the same call sequence ``stream_open``, ``stream_step`` (until
+* **Identical per-engine schedules.** An engine's life under the executor
+  is the same call sequence ``stream_open``, ``stream_step`` (until
   exhausted or budget), ``stream_close`` that the sequential
   ``ServingEngine.run`` makes — only interleaved *across* engines, which no
   engine can observe (nothing is shared). Outputs, finish reasons and every
@@ -42,7 +42,7 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Sequence
 
-from repro.runtime.serving import Request
+from repro.runtime.serving import RUN_BUDGET_MAX_LENS, Request
 
 
 class FleetExecutor:
@@ -85,67 +85,56 @@ class FleetExecutor:
             time.sleep(self.dwell_s)  # emulated accelerator round-trip
         return out
 
-    def run(self, max_waves: int = 64,
-            max_steps: Optional[int] = None) -> list[Request]:
+    def run(self, max_steps: Optional[int] = None) -> list[Request]:
         """Drain every engine concurrently; returns finished requests in
         the sequential drain's order (engine binding order, completion
         order within an engine). Budget semantics match
         :meth:`~repro.runtime.serving.ServingEngine.run`: per-engine
-        ``max_steps`` steps (default ``max_waves * max_len``); wave-mode
-        engines run whole on a worker each (their scheduler has no
-        single-step surface, but they share nothing either)."""
-        stream = [b for b in self.bindings
-                  if b.engine.scheduler == "stream"]
-        waves = [b for b in self.bindings if b.engine.scheduler != "stream"]
+        ``max_steps`` steps (default ``RUN_BUDGET_MAX_LENS * max_len``)."""
         self.ticks = 0
         done_by: dict[str, list[Request]] = {b.name: [] for b in self.bindings}
+        budgets = {b.name: (max_steps if max_steps is not None
+                            else RUN_BUDGET_MAX_LENS * b.engine.max_len)
+                   for b in self.bindings}
         with ThreadPoolExecutor(max_workers=self.max_workers) as pool:
-            wave_futs = [(b, pool.submit(b.engine.run, max_waves, max_steps))
-                         for b in waves]
-            if stream:
-                budgets = {b.name: (max_steps if max_steps is not None
-                                    else max_waves * b.engine.max_len)
-                           for b in stream}
-                for b in stream:
-                    b.engine.stream_open()
-                live = list(stream)
-                try:
-                    while live:
-                        # one lockstep tick: at most one in-flight step per
-                        # engine; gathering the futures is the barrier that
-                        # orders this tick's writes before the next tick
-                        futs = [(b, pool.submit(self._step_engine, b))
-                                for b in live]
-                        self.ticks += 1
-                        nxt = []
-                        for b, fut in futs:
-                            finished = fut.result()
-                            if finished is None:  # exhausted (or not awake)
-                                continue
-                            done_by[b.name].extend(finished)
-                            budgets[b.name] -= 1
-                            if budgets[b.name] > 0:
-                                nxt.append(b)
-                        live = nxt
-                        if self.on_tick is not None:
-                            self.on_tick(self.ticks)
-                            # revival: the hook may have migrated a slot
-                            # into (or woken) an engine that had idled out
-                            # of the live set — an awake engine with slot
-                            # or queue work and budget re-enters the
-                            # lockstep. Without a hook nothing can touch a
-                            # dropped engine, so this is unreachable and
-                            # the schedule is byte-identical to PR 9's.
-                            in_live = {b.name for b in live}
-                            for b in stream:
-                                if (b.name not in in_live
-                                        and budgets[b.name] > 0
-                                        and b.engine.power_state == "awake"
-                                        and b.engine.stream_busy()):
-                                    live.append(b)
-                finally:
-                    for b in stream:
-                        b.engine.stream_close()
-            for b, fut in wave_futs:
-                done_by[b.name].extend(fut.result())
+            for b in self.bindings:
+                b.engine.stream_open()
+            live = list(self.bindings)
+            try:
+                while live:
+                    # one lockstep tick: at most one in-flight step per
+                    # engine; gathering the futures is the barrier that
+                    # orders this tick's writes before the next tick
+                    futs = [(b, pool.submit(self._step_engine, b))
+                            for b in live]
+                    self.ticks += 1
+                    nxt = []
+                    for b, fut in futs:
+                        finished = fut.result()
+                        if finished is None:  # exhausted (or not awake)
+                            continue
+                        done_by[b.name].extend(finished)
+                        budgets[b.name] -= 1
+                        if budgets[b.name] > 0:
+                            nxt.append(b)
+                    live = nxt
+                    if self.on_tick is not None:
+                        self.on_tick(self.ticks)
+                        # revival: the hook may have migrated a slot into
+                        # (or woken) an engine that had idled out of the
+                        # live set — an awake engine with slot or queue
+                        # work and budget re-enters the lockstep. Without a
+                        # hook nothing can touch a dropped engine, so this
+                        # is unreachable and the schedule is the plain
+                        # lockstep's.
+                        in_live = {b.name for b in live}
+                        for b in self.bindings:
+                            if (b.name not in in_live
+                                    and budgets[b.name] > 0
+                                    and b.engine.power_state == "awake"
+                                    and b.engine.stream_busy()):
+                                live.append(b)
+            finally:
+                for b in self.bindings:
+                    b.engine.stream_close()
         return [r for b in self.bindings for r in done_by[b.name]]

@@ -107,28 +107,19 @@ def _leaf_manifest(leaves: dict) -> tuple[dict, int]:
     return manifest, nbytes
 
 
-def _session(engine: ServingEngine) -> tuple[str, dict]:
-    if engine._stream is not None:
-        return "stream", engine._stream
-    if engine._wave is not None:
-        return "wave", engine._wave
-    raise MigrationError(
-        f"engine {engine.name!r} has no open session to migrate through")
+def _session(engine: ServingEngine) -> dict:
+    if engine._stream is None:
+        raise MigrationError(
+            f"engine {engine.name!r} has no open session to migrate through")
+    return engine._stream
 
 
 def free_slots(engine: ServingEngine) -> list[int]:
     """Slot indices of the open session a snapshot could restore into
     ([] when no session is open)."""
-    if engine._stream is not None:
-        return [i for i, r in enumerate(engine._stream["slot_req"])
-                if r is None]
-    if engine._wave is not None:
-        w = engine._wave
-        # a wave session can grow up to the engine's slot count; inactive
-        # wave members keep their slot (their state rows are dead but the
-        # wave never refills them — the wave semantics)
-        return list(range(len(w["reqs"]), engine.slots))
-    return []
+    if engine._stream is None:
+        return []
+    return [i for i, r in enumerate(engine._stream["slot_req"]) if r is None]
 
 
 def _cache_len(tree: Any, axis: int) -> int:
@@ -139,20 +130,14 @@ def snapshot_slot(engine: ServingEngine, slot: int) -> SlotSnapshot:
     """Pure host-side snapshot of occupied ``slot`` in ``engine``'s open
     session. Read-only on the engine: pair with :func:`detach_slot` (or use
     :func:`migrate`) to actually move the request."""
-    kind, s = _session(engine)
+    s = _session(engine)
     if engine.power_state != "awake":
         # unreachable through the state machine (sleep/floor require
         # idleness), but state surgery deserves a belt
         raise MigrationError(
             f"source {engine.name!r} is {engine.power_state}; only an "
             f"awake engine's decode state is coherent to snapshot")
-    if kind == "stream":
-        reqs, cursors, caps = s["slot_req"], s["cursors"], s["cap"]
-    else:
-        reqs, cursors, caps = s["reqs"], s["cursors"], s["cap"]
-        if slot < len(reqs) and not s["active"][slot]:
-            raise MigrationError(
-                f"slot {slot} of {engine.name!r} already finished its wave")
+    reqs, cursors, caps = s["slot_req"], s["cursors"], s["cap"]
     if slot < 0 or slot >= len(reqs) or reqs[slot] is None:
         raise MigrationError(
             f"slot {slot} of {engine.name!r} holds no admitted request")
@@ -167,20 +152,14 @@ def snapshot_slot(engine: ServingEngine, slot: int) -> SlotSnapshot:
 
 def detach_slot(engine: ServingEngine, slot: int) -> Request:
     """Release ``slot`` on the source after its snapshot restored elsewhere:
-    the slot frees (a stream slot re-admits from the queue next step), the
+    the slot frees (it re-admits from the queue next step), the
     request leaves ``engine.active`` and ``migrations_out`` ticks. No token
     is un-billed — everything decoded here was genuinely served here."""
-    kind, s = _session(engine)
-    if kind == "stream":
-        req = s["slot_req"][slot]
-        if req is None:
-            raise MigrationError(f"slot {slot} of {engine.name!r} is free")
-        s["slot_req"][slot] = None
-    else:
-        if slot >= len(s["reqs"]) or not s["active"][slot]:
-            raise MigrationError(f"slot {slot} of {engine.name!r} is free")
-        req = s["reqs"][slot]
-        s["active"][slot] = False
+    s = _session(engine)
+    req = s["slot_req"][slot]
+    if req is None:
+        raise MigrationError(f"slot {slot} of {engine.name!r} is free")
+    s["slot_req"][slot] = None
     engine.active.remove(req)
     engine.stats.migrations_out += 1
     return req
@@ -246,7 +225,7 @@ def restore_slot(engine: ServingEngine, snap: SlotSnapshot, *,
             raise MigrationError(
                 f"target {engine.name!r} is waking until "
                 f"t={engine._awake_at:.3f}; retry after the wake latency")
-    kind, s = _session(engine)
+    s = _session(engine)
     free = free_slots(engine)
     if not free:
         raise MigrationError(f"target {engine.name!r} has no free slot")
@@ -263,17 +242,10 @@ def restore_slot(engine: ServingEngine, snap: SlotSnapshot, *,
     s["state"] = T.restore_decode_slot(engine.cfg, s["state"], slot,
                                        leaves, snap.pos)
     req = snap.request
-    if kind == "stream":
-        s["slot_req"][slot] = req
-        s["cursors"][slot] = snap.cursor
-        s["epoch"][slot] = dict(engine.placements)
-        s["cap"][slot] = snap.cap
-    else:
-        s["reqs"].append(req)
-        s["cursors"].append(snap.cursor)
-        s["active"].append(True)
-        s["epoch"].append(dict(engine.placements))
-        s["cap"].append(snap.cap)
+    s["slot_req"][slot] = req
+    s["cursors"][slot] = snap.cursor
+    s["epoch"][slot] = dict(engine.placements)
+    s["cap"][slot] = snap.cap
     req.served_by = engine.name
     billed = engine.placements.get("decode") or engine.placements.get(
         "prefill")

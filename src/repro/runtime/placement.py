@@ -10,10 +10,9 @@ than to a hand-chosen offline cell. The controller closes that loop:
    (prefill vs decode token shares), the batch occupancy of the scheduler,
    and the tightest per-step time budget implied by pending request SLOs.
    Occupancy is quantized into quarter buckets so observed cells form a
-   small stable set and the measurement cache stays hot. Under the
-   slot-stream scheduler the window is a **step count** (``interval_steps``
-   via the engine's ``on_step_end`` hook — there are no wave boundaries);
-   under the wave scheduler it stays ``interval_waves``.
+   small stable set and the measurement cache stays hot. The window is a
+   **step count** (``interval_steps`` via the engine's ``on_step_end``
+   hook).
 2. **sweep** — map the observed mix to fleet cells (arch × bucketed shape ×
    candidate destination mesh) and run
    :func:`~repro.core.offload_search.search_fleet` over them through an
@@ -33,9 +32,8 @@ than to a hand-chosen offline cell. The controller closes that loop:
    time SLO and energy jointly, as in mixed-destination selection). The
    chosen pattern fixes cell, destination *and* the DVFS clock gene jointly.
 4. **reconfigure** — apply the chosen :class:`Placement`s to the engine.
-   Under slot streams the swap applies to newly admitted slots (in-flight
-   requests keep their admission epoch), so it is safe mid-run; the wave
-   scheduler keeps the between-waves-only rule.
+   The swap applies to newly admitted slots (in-flight requests keep their
+   admission epoch), so it is safe mid-run.
 
 ``benchmarks/serving_bench.py`` drives this loop under prefill-heavy,
 decode-heavy and mixed-burst traffic and reports Watt·s per 1k tokens
@@ -216,10 +214,10 @@ def static_placements(
 class PlacementController:
     """Drives ``search_fleet`` placement from the live serving loop.
 
-    Attach to a :class:`ServingEngine` and every ``interval_waves`` waves the
-    controller re-plans from the traffic observed since its last sweep. All
-    sweeps share ``eval_engine``'s (optionally disk-persisted) measurement
-    cache.
+    Attach to a :class:`ServingEngine` and every ``interval_steps`` engine
+    steps the controller re-plans from the traffic observed since its last
+    sweep. All sweeps share ``eval_engine``'s (optionally disk-persisted)
+    measurement cache.
     """
 
     def __init__(
@@ -236,7 +234,6 @@ class PlacementController:
         require_energy_improvement: bool = True,
         catalog: Optional[dict[str, ShapeSpec]] = None,
         power: TpuPowerModel = TpuPowerModel(),
-        interval_waves: int = 4,
         interval_steps: int = 32,
         min_kind_weight: float = 0.02,
         prefer: str = "energy",
@@ -269,7 +266,6 @@ class PlacementController:
         self.require_energy_improvement = require_energy_improvement
         self.catalog = dict(catalog or DEFAULT_CATALOG)
         self.power = power
-        self.interval_waves = interval_waves
         self.interval_steps = interval_steps
         self.min_kind_weight = min_kind_weight
         self.prefer = prefer
@@ -278,28 +274,15 @@ class PlacementController:
         self.drift: dict[str, float] = {}  # kind -> (metered/modeled) - 1
         self.history: list[PlanReport] = []
         self._last_stats = engine.stats.snapshot()
-        self._waves_since = 0
         self._steps_since = 0
         self._resweep_pending = False
 
     # -- wiring --------------------------------------------------------
     def attach(self) -> "PlacementController":
-        """Register on the engine's observation hooks: ``on_wave_end``
-        (wave scheduler, ``interval_waves`` window) and ``on_step_end``
-        (slot streams have no wave boundaries — the window is
-        ``interval_steps`` engine steps). Each scheduler only fires its own
-        hook, so the windows never double-count."""
-        self.engine.on_wave_end = self._on_wave_end
-        if hasattr(self.engine, "on_step_end"):
-            self.engine.on_step_end = self._on_step_end
+        """Register on the engine's ``on_step_end`` hook: the observation
+        window is ``interval_steps`` engine steps."""
+        self.engine.on_step_end = self._on_step_end
         return self
-
-    def _on_wave_end(self, engine: ServingEngine) -> None:
-        self._waves_since += 1
-        if self._resweep_pending or self._waves_since >= self.interval_waves:
-            self._waves_since = 0
-            self._resweep_pending = False
-            self.update()
 
     def _on_step_end(self, engine: ServingEngine) -> None:
         self._steps_since += 1
@@ -316,8 +299,8 @@ class PlacementController:
         Two effects: the engine's energy ledger is recalibrated by the
         metered/modeled ratio (so accumulated Watt·s track the measurement,
         not the model), and when the drift exceeds ``drift_threshold`` a
-        re-sweep is scheduled for the next between-waves point regardless of
-        ``interval_waves`` — the model the current placement was chosen by
+        re-sweep is scheduled for the next engine step regardless of
+        ``interval_steps`` — the model the current placement was chosen by
         has been falsified by measurement, so the choice itself is suspect.
         Returns True when a re-sweep was triggered.
         """

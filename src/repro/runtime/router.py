@@ -136,7 +136,6 @@ class FleetRouter:
         policy: str = "energy",
         slots: int = 4,
         max_len: int = 64,
-        scheduler: str = "stream",
         overflow: str = "reject",
         cache_path: Optional[str] = "results/eval_cache.jsonl",
         cache_compact: bool = True,
@@ -193,8 +192,7 @@ class FleetRouter:
             else:
                 name = d.name
             engine = ServingEngine(cfg, params, slots=slots, max_len=max_len,
-                                   overflow=overflow, scheduler=scheduler,
-                                   name=name)
+                                   overflow=overflow, name=name)
             engine.reconfigure(static_placements(
                 arch, d.mesh_shape, catalog=self.catalog, power=d.power,
                 destination=d.name))
@@ -365,8 +363,7 @@ class FleetRouter:
             self.rejected.append(req)
         return ok
 
-    def run(self, max_waves: int = 64,
-            max_steps: Optional[int] = None, *,
+    def run(self, max_steps: Optional[int] = None, *,
             concurrent: bool = False,
             max_workers: Optional[int] = None,
             dwell_s: float = 0.0,
@@ -403,11 +400,10 @@ class FleetRouter:
                         _user(tick)
             ex = FleetExecutor(self._bindings, max_workers=max_workers,
                                dwell_s=dwell_s, on_tick=on_tick)
-            return ex.run(max_waves=max_waves, max_steps=max_steps)
+            return ex.run(max_steps=max_steps)
         done: list[Request] = []
         for b in self._bindings:
-            done.extend(b.engine.run(max_waves=max_waves,
-                                     max_steps=max_steps))
+            done.extend(b.engine.run(max_steps=max_steps))
         return done
 
     # -- observe (union traffic mix) -----------------------------------
@@ -666,20 +662,18 @@ class FleetRouter:
         from repro.runtime.migration import migrate
         src = next(b for b in self._bindings if b.name == source)
         dst = next(b for b in self._bindings if b.name == target)
-        req, _ = self._slot_request(src, slot)
+        req = self._slot_request(src, slot)
         out = migrate(src.engine, dst.engine, slot, now=now)
         self.assignments[req.rid] = dst.name
         return out
 
     def _slot_request(self, binding: EngineBinding, slot: int):
         from repro.runtime import migration
-        sess_kind, s = migration._session(binding.engine)
-        reqs = s["slot_req"] if sess_kind == "stream" else s["reqs"]
+        reqs = migration._session(binding.engine)["slot_req"]
         if slot >= len(reqs) or reqs[slot] is None:
-            from repro.runtime.migration import MigrationError
-            raise MigrationError(
+            raise migration.MigrationError(
                 f"slot {slot} of {binding.name!r} holds no request")
-        return reqs[slot], sess_kind
+        return reqs[slot]
 
     def _live_shed(self, source: EngineBinding,
                    survivors: Sequence[EngineBinding],
@@ -692,14 +686,12 @@ class FleetRouter:
         from repro.runtime import migration
         moved = 0
         try:
-            sess_kind, s = migration._session(source.engine)
+            reqs = migration._session(source.engine)["slot_req"]
         except migration.MigrationError:
             return 0
-        reqs = s["slot_req"] if sess_kind == "stream" else s["reqs"]
         for slot in range(len(reqs)):
             req = reqs[slot]
-            if req is None or (sess_kind == "wave"
-                               and not s["active"][slot]):
+            if req is None:
                 continue
             cands = []
             for b in survivors:

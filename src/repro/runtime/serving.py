@@ -1,28 +1,19 @@
-"""Batched serving loop — slot-stream continuous batching (default) with the
-legacy wave scheduler kept behind ``scheduler="wave"``.
+"""Batched serving loop — slot-stream continuous batching.
 
-**Slot streams** (``scheduler="stream"``): each of the B slots carries its own
-position stream inside one shared decode state (``models/transformer.py``
-grew per-slot positions + ``reset_decode_slots``). A slot admits the next
-queued request the step after its previous occupant finishes: the freed slot
-is masked-reset (position back to 0, recurrent state re-initialized) while
-its neighbors keep decoding, so cache positions never alias across the
-requests sharing a slot — exactness is preserved for all architecture
-families, and for any fixed request set the decoded outputs are
-token-identical to the wave scheduler's. A dense model's prompt is
-prefilled at admission: all but its last token go through
+**Slot streams**: each of the B slots carries its own position stream
+inside one shared decode state (``models/transformer.py`` grew per-slot
+positions + ``reset_decode_slots``). A slot admits the next queued request
+the step after its previous occupant finishes: the freed slot is
+masked-reset (position back to 0, recurrent state re-initialized) while its
+neighbors keep decoding, so cache positions never alias across the requests
+sharing a slot — exactness is preserved for all architecture families, and
+each request decodes the same tokens it would if served alone. A dense
+model's prompt is prefilled at admission: all but its last token go through
 ``transformer.prefill_chunk`` in chunks of ``prefill_len`` tokens, and the
 decode step of the admitting step takes the last one and emits the first
 output token. Every other family feeds its prompt a token a step
 ("prefill-as-decode" — exact for SSM/hybrid too, since the decode step IS
 the recurrence).
-
-**Waves** (``scheduler="wave"``): requests are admitted in waves of up to B
-slots sharing one fresh decode state; finished slots idle out until the
-whole wave drains. This is the pre-slot-stream design, retained so existing
-comparisons stay reproducible — the occupancy it leaves on the table on
-ragged-length traffic is exactly what ``benchmarks/serving_bench.py``'s
-ragged scenario measures.
 
 Placement integration: the engine carries per-shape-kind :class:`Placement`
 records (chosen by ``runtime/placement.py`` from fleet Pareto frontiers)
@@ -30,17 +21,15 @@ whose per-token energy rates accumulate into ``EngineStats.energy_ws`` —
 the modeled Watt·s the offload search is minimizing, attributed to live
 traffic. Every token is costed under the **placement epoch active at its
 slot's admission**: ``reconfigure`` applies to newly admitted slots, so a
-mid-stream swap never re-prices in-flight requests (in wave mode this
-degenerates to the old "reconfigure only between waves" rule, which
-``reconfigure`` still enforces there). ``Placement.time_per_token_s``
-additionally makes admission placement-aware: each admitted request gets a
-modeled completion latency, checked against its optional ``slo_s`` and
-exported to the controller (``slo_time_per_step_s``) so latency SLOs join
-energy in the §3.3 narrowing.
+mid-stream swap never re-prices in-flight requests.
+``Placement.time_per_token_s`` additionally makes admission
+placement-aware: each admitted request gets a modeled completion latency,
+checked against its optional ``slo_s`` and exported to the controller
+(``slo_time_per_step_s``) so latency SLOs join energy in the §3.3
+narrowing.
 
-Hooks: ``on_step_end`` fires after every stream step (the controller's
-step-count observation window); ``on_wave_end`` fires after each wave in
-wave mode.
+Hook: ``on_step_end`` fires after every stream step (the controller's
+step-count observation window).
 
 **Power states** (energy-proportional serving): an engine is ``awake``
 (full static draw, serves), at the DVFS ``floor`` (reduced static draw,
@@ -58,8 +47,7 @@ and the workload driver (``workload/driver.py``) advances the clock.
 **Stream sessions**: ``stream_open`` / ``stream_step`` / ``stream_close``
 expose the slot-stream loop one step at a time, so a simulator can
 interleave open-loop arrivals, power transitions and engine steps on one
-virtual clock. ``run()`` is implemented on top of them and stays
-token-identical to the pre-session loop.
+virtual clock. ``run()`` is implemented on top of them.
 
 See ``docs/ARCHITECTURE.md`` for how the engine, the placement controller,
 the telemetry loop and the fleet router fit together.
@@ -119,7 +107,6 @@ class Request:
 @dataclass
 class EngineStats:
     steps: int = 0
-    waves: int = 0  # wave scheduler only; 0 under slot streams
     admissions: int = 0  # requests admitted into a slot
     prefill_tokens: int = 0
     decode_tokens: int = 0
@@ -128,7 +115,7 @@ class EngineStats:
     slo_at_risk: int = 0  # admissions whose modeled latency exceeds slo_s
     rejected: int = 0  # refused at submit (prompt cannot fit max_len)
     truncated: int = 0  # admitted with a clipped prompt
-    incomplete: int = 0  # step/wave budget exhausted before completion
+    incomplete: int = 0  # step budget exhausted before completion
     slot_steps: int = 0  # slots x steps: the occupancy denominator
     active_slot_steps: int = 0  # slots actually decoding a request
     energy_ws: float = 0.0  # modeled Watt·s under the applied placements
@@ -221,11 +208,13 @@ POWER_STATES = ("awake", "floor", "asleep", "waking")
 #: prompt tokens one chunked-prefill program consumes (capped at max_len)
 PREFILL_CHUNK = 256
 
+#: ``run()``'s step budget, in multiples of ``max_len``
+RUN_BUDGET_MAX_LENS = 64
+
 
 class ServingEngine:
     """Greedy decoding over ``decode_step`` with slot-stream continuous
-    batching (``scheduler="stream"``, default) or wave batching
-    (``scheduler="wave"``).
+    batching.
 
     ``overflow`` is the admission policy for prompts that cannot leave room
     for a single generated token within ``max_len``:
@@ -249,18 +238,15 @@ class ServingEngine:
 
     def __init__(self, cfg: ArchConfig, params, *, slots: int = 4,
                  max_len: int = 256, overflow: str = "reject",
-                 scheduler: str = "stream", name: str = "engine"):
+                 name: str = "engine"):
         if overflow not in ("reject", "truncate"):
             raise ValueError(f"unknown overflow policy {overflow!r}")
-        if scheduler not in ("stream", "wave"):
-            raise ValueError(f"unknown scheduler {scheduler!r}")
         self.cfg = cfg
         self.params = params
         self.name = name  # serving-attribution label (fleet router names us)
         self.slots = slots
         self.max_len = max_len
         self.overflow = overflow
-        self.scheduler = scheduler
         self.queue: deque[Request] = deque()
         self.rejected: list[Request] = []
         self.active: list[Request] = []  # currently admitted, not finished
@@ -272,9 +258,7 @@ class ServingEngine:
         # model. 1.0 (absent) = trust the model. Corrections are live
         # calibration state, so they apply across placement epochs.
         self.energy_correction: dict[str, float] = {}
-        self.on_wave_end: Optional[Callable[["ServingEngine"], None]] = None
         self.on_step_end: Optional[Callable[["ServingEngine"], None]] = None
-        self._in_wave = False
         # power state machine (energy-proportional serving). Watts default
         # to 0.0 so legacy paths that never call set_power/accrue_idle keep
         # a byte-identical ledger.
@@ -286,11 +270,10 @@ class ServingEngine:
         self.floor_wake_s = 0.0  # floor -> awake latency (near-instant)
         self._awake_at = 0.0  # when a "waking" engine finishes waking
         self._stream: Optional[dict] = None  # open stream session state
-        self._wave: Optional[dict] = None  # open wave session state
         self.last_step_s = 0.0  # modeled duration of the last stream step
         # Donating the state matches launch/steps.build_serve_step: the old
-        # KV/recurrent buffers are dead after every call site (both the
-        # stream and wave paths rebind), so XLA updates the cache in place
+        # KV/recurrent buffers are dead after every call site (the stream
+        # session rebinds them), so XLA updates the cache in place
         # instead of paying a copy + double HBM residency per token. A named
         # function names the device program: jit_decode_step.
         def decode_step(params, state, tokens):
@@ -446,15 +429,10 @@ class ServingEngine:
 
     # ------------------------------------------------------------------
     def reconfigure(self, placements: Mapping[str, Placement]) -> None:
-        """Swap per-kind placements. Under slot streams the swap applies to
-        **newly admitted slots**: in-flight requests keep the epoch they were
-        admitted under, so calling this mid-run is safe and is exactly how
-        the step-windowed controller reconfigures. The wave scheduler keeps
-        the stricter legacy rule (never mid-wave; a wave's tokens are costed
-        under the placement that admitted it)."""
-        if self._in_wave:
-            raise RuntimeError("reconfigure() during a wave; use the "
-                               "on_wave_end hook to apply between waves")
+        """Swap per-kind placements. The swap applies to **newly admitted
+        slots**: in-flight requests keep the epoch they were admitted under,
+        so calling this mid-run is safe and is exactly how the step-windowed
+        controller reconfigures."""
         was_configured = bool(self.placements)
         self.placements = dict(placements)
         if was_configured:  # the first application is configuration, not RE-
@@ -508,7 +486,7 @@ class ServingEngine:
         return min(budgets) if budgets else None
 
     def _admit(self, req: Request) -> None:
-        """Common admission bookkeeping (both schedulers)."""
+        """Admission bookkeeping."""
         if req.status == "queued":
             req.status = "active"
         if req.admit_t is None:
@@ -735,7 +713,7 @@ class ServingEngine:
     # ------------------------------------------------------------------
     def snapshot_slot(self, slot: int):
         """Pure host-side :class:`~repro.runtime.migration.SlotSnapshot` of
-        one occupied slot of the open session (stream or wave). Read-only:
+        one occupied slot of the open session. Read-only:
         detaching the slot is the transactional move's job
         (:func:`repro.runtime.migration.migrate`)."""
         from repro.runtime import migration
@@ -754,7 +732,12 @@ class ServingEngine:
             kwargs["transfer_ws_per_mib"] = transfer_ws_per_mib
         return migration.restore_slot(self, snap, now=now, **kwargs)
 
-    def _run_stream(self, max_steps: int) -> list[Request]:
+    def run(self, max_steps: Optional[int] = None) -> list[Request]:
+        """Serve the queue; returns the *finished* requests in completion
+        order. ``max_steps`` bounds the session (default
+        ``RUN_BUDGET_MAX_LENS * max_len``)."""
+        if max_steps is None:
+            max_steps = RUN_BUDGET_MAX_LENS * self.max_len
         self.stream_open()
         done: list[Request] = []
         try:
@@ -765,130 +748,4 @@ class ServingEngine:
                 done.extend(finished)
         finally:
             self.stream_close()
-        return done
-
-    # ------------------------------------------------------------------
-    # Wave scheduler (legacy, scheduler="wave"; session API mirrors the
-    # stream scheduler's so mid-flight migration works under both)
-    # ------------------------------------------------------------------
-    def wave_open(self, wave: list[Request]) -> None:
-        """Start a wave session over up to ``slots`` requests: one fresh
-        decode state plus per-slot bookkeeping held on the engine, so a
-        test or migration driver can step the wave incrementally (the
-        legacy closed loop, ``_run_wave``, is now a thin driver over this).
-        Epoch and cap are tracked per slot — identical for every admitted
-        member (the wave rule), but a slot restored by mid-flight migration
-        carries its own."""
-        if self._wave is not None:
-            raise RuntimeError("wave session already open")
-        self.stats.waves += 1
-        self._in_wave = True
-        self._wave = {
-            "state": T.init_decode_state(self.cfg, self.slots, self.max_len),
-            "reqs": list(wave),
-            "cursors": [0] * len(wave),
-            "active": [True] * len(wave),
-            "epoch": [dict(self.placements) for _ in wave],
-            "cap": [self.max_len] * len(wave),
-            "steps": 0,
-        }
-        for i, req in enumerate(wave):
-            self._admit(req)
-            self._wave["state"], self._wave["cursors"][i] = \
-                self._prefill_prompt(self._wave["state"], i, req,
-                                     self._wave["epoch"][i])
-
-    def wave_step(self) -> Optional[list[Request]]:
-        """One decode step of the open wave session. Returns the requests
-        finished by this step, or None when the wave is drained (or its
-        ``max_len`` step bound — unreachable under the submit guard — is
-        exhausted)."""
-        if self._wave is None:
-            raise RuntimeError("no open wave session")
-        w = self._wave
-        reqs, cursors, active = w["reqs"], w["cursors"], w["active"]
-        if not any(active) or w["steps"] >= self.max_len:
-            return None
-        tokens = np.zeros((self.slots,), np.int32)
-        for i, req in enumerate(reqs):
-            if not active[i]:
-                continue
-            c = cursors[i]
-            tokens[i] = (req.prompt[c] if c < len(req.prompt)
-                         else req.output[-1])
-        logits, w["state"] = self._step(self.params, w["state"],
-                                        jnp.asarray(tokens))
-        w["steps"] += 1
-        self.stats.steps += 1
-        self.stats.slot_steps += self.slots
-        self.stats.active_slot_steps += sum(active)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        done: list[Request] = []
-        for i, req in enumerate(reqs):
-            if not active[i]:
-                continue
-            c = cursors[i]
-            cursors[i] += 1
-            # prefill/decode attribution: the step consuming the
-            # last prompt token is prefill (see _run_stream)
-            kind = "prefill" if c < len(req.prompt) else "decode"
-            self.stats.prefill_tokens += kind == "prefill"
-            self.stats.decode_tokens += kind == "decode"
-            self.stats.energy_ws += self._token_energy(kind, w["epoch"][i])
-            if c >= len(req.prompt) - 1:
-                tok = int(nxt[i])
-                req.output.append(tok)
-                if req.first_token_t is None:
-                    req.first_token_t = time.perf_counter()
-                reason = self._finish_reason(req, tok, cursors[i],
-                                             w["cap"][i])
-                if reason is not None:
-                    self._finish(req, reason)
-                    done.append(req)
-                    active[i] = False
-        return done
-
-    def wave_close(self) -> None:
-        """End the wave session. Still-active slots are marked
-        ``incomplete`` (the submit guard makes wave exhaustion unreachable,
-        but if it ever happens the request is marked, not laundered as
-        done)."""
-        if self._wave is None:
-            return
-        for i, req in enumerate(self._wave["reqs"]):
-            if self._wave["active"][i]:
-                req.status = "incomplete"
-                self.stats.incomplete += 1
-                self.active.remove(req)
-        self._wave = None
-        self._in_wave = False
-
-    def _run_wave(self, wave: list[Request]) -> None:
-        self.wave_open(wave)
-        try:
-            while self.wave_step() is not None:
-                pass
-        finally:
-            self.wave_close()
-
-    def run(self, max_waves: int = 64,
-            max_steps: Optional[int] = None) -> list[Request]:
-        """Serve the queue; returns the *finished* requests in completion
-        order. Under slot streams the budget is ``max_steps`` (default
-        ``max_waves * max_len``, the same work ceiling the wave scheduler
-        had); ``max_waves`` bounds the wave scheduler."""
-        if self.scheduler == "stream":
-            if max_steps is None:
-                max_steps = max_waves * self.max_len
-            return self._run_stream(max_steps)
-        done: list[Request] = []
-        for _ in range(max_waves):
-            if not self.queue:
-                break
-            wave = [self.queue.popleft()
-                    for _ in range(min(self.slots, len(self.queue)))]
-            self._run_wave(wave)
-            done.extend(r for r in wave if r.done)
-            if self.on_wave_end is not None:
-                self.on_wave_end(self)
         return done

@@ -12,8 +12,8 @@ no-token-billed-twice fleet-ledger conservation as property tests
 (``_hypothesis_compat``), the sleep→migrate→drain power-guard regression,
 the deterministic geometry refusals (sliding-window ring mismatch, target
 cache too short, digest tamper, no free slot — all transactional: the
-source is untouched), transfer-cost billing, cap-carry semantics, wave
-scheduler migration, and the router's live rebalance escalation.
+source is untouched), transfer-cost billing, cap-carry semantics, and the
+router's live rebalance escalation.
 """
 import random
 
@@ -173,38 +173,6 @@ def test_migrated_traffic_token_identical(family, point):
     # the move billed as a transfer-cost line on the target, nowhere else
     assert dst.stats.migration_ws > 0.0
     assert src.stats.migration_ws == 0.0
-
-
-def test_wave_scheduler_migration_token_identical():
-    """The legacy wave scheduler migrates too: a mid-wave slot moves to an
-    empty wave on a roomier engine and the wave's outputs are unchanged."""
-    cfg, params = _model("llama3.2-3b")
-    base = ServingEngine(cfg, params, scheduler="wave", slots=2, max_len=32)
-    base_rs = _requests()[:2]
-    for r in base_rs:
-        base.submit(r)
-    base.run()
-
-    src = ServingEngine(cfg, params, scheduler="wave", slots=2, max_len=32,
-                        name="src")
-    dst = ServingEngine(cfg, params, scheduler="wave", slots=2, max_len=48,
-                        name="dst")
-    rs = _requests()[:2]
-    src.wave_open(rs)
-    dst.wave_open([])
-    for _ in range(4):
-        src.wave_step()
-    migrate(src, dst, 0)
-    for _ in range(200):
-        f = src.wave_step()
-        g = dst.wave_step()
-        if f is None and g is None:
-            break
-    src.wave_close()
-    dst.wave_close()
-    assert _record(rs) == _record(base_rs)
-    assert src.stats.migrations_out == 1
-    assert dst.stats.migrations_in == 1
 
 
 # ---------------------------------------------------------------------------

@@ -23,13 +23,12 @@ GA = GAConfig(population=8, generations=6, seed=0)
 
 class FakeEngine:
     """Just enough engine surface for the controller: stats + placements +
-    the between-waves reconfigure contract."""
+    the reconfigure contract."""
 
     def __init__(self):
         self.stats = EngineStats()
         self.placements = {}
         self.energy_correction = {}
-        self.on_wave_end = None
         self.on_step_end = None
 
     def reconfigure(self, placements):
@@ -169,8 +168,8 @@ def test_repeat_plan_hits_persistent_cache(tmp_path):
 
 
 def test_step_window_controller_updates_on_interval_steps(tmp_path):
-    """Slot streams have no wave boundaries: the controller observes on a
-    step-count window through the engine's on_step_end hook."""
+    """The controller observes on a step-count window through the engine's
+    on_step_end hook."""
     eng, ctrl = make_controller(tmp_path, interval_steps=4)
     ctrl.attach()
     assert eng.on_step_end == ctrl._on_step_end
@@ -211,7 +210,7 @@ def test_slo_budget_joins_narrowing_requirement(tmp_path):
 
 
 def test_note_metered_calibrates_ledger_without_resweep(tmp_path):
-    eng, ctrl = make_controller(tmp_path, interval_waves=100,
+    eng, ctrl = make_controller(tmp_path, interval_steps=100,
                                 drift_threshold=0.2)
     eng.reconfigure(static_placements("llama3.2-3b", MESH0))
     modeled = eng.placements["decode"].energy_per_token_ws
@@ -220,23 +219,23 @@ def test_note_metered_calibrates_ledger_without_resweep(tmp_path):
     assert eng.energy_correction["decode"] == pytest.approx(1.1)
     assert ctrl.drift["decode"] == pytest.approx(0.1)
     before = len(ctrl.history)
-    ctrl._on_wave_end(eng)  # far from the 100-wave interval
+    ctrl._on_step_end(eng)  # far from the 100-step interval
     assert len(ctrl.history) == before
 
 
 def test_note_metered_drift_triggers_off_interval_resweep(tmp_path):
-    eng, ctrl = make_controller(tmp_path, interval_waves=100,
+    eng, ctrl = make_controller(tmp_path, interval_steps=100,
                                 drift_threshold=0.2)
     eng.reconfigure(static_placements("llama3.2-3b", MESH0))
     modeled = eng.placements["decode"].energy_per_token_ws
     # 50% drift: the model the placement was chosen by is falsified
     assert ctrl.note_metered("decode", modeled * 1.5) is True
     _traffic(eng, prefill=2, decode=398, slot_steps=400, active=400)
-    ctrl._on_wave_end(eng)  # wave 1 of 100 — but the drift forces a re-plan
+    ctrl._on_step_end(eng)  # step 1 of 100 — but the drift forces a re-plan
     assert len(ctrl.history) == 1
     assert eng.placements["decode"].source == "adaptive"
     # the pending flag is one-shot
-    ctrl._on_wave_end(eng)
+    ctrl._on_step_end(eng)
     assert len(ctrl.history) == 1
 
 
@@ -259,14 +258,14 @@ def test_note_metered_rejects_zero_metered_rate(tmp_path):
 def test_replan_resets_stale_energy_correction(tmp_path):
     """A re-sweep installs a new placement; the correction ratio measured
     against the OLD placement must not keep scaling the new one."""
-    eng, ctrl = make_controller(tmp_path, interval_waves=100,
+    eng, ctrl = make_controller(tmp_path, interval_steps=100,
                                 drift_threshold=0.2)
     eng.reconfigure(static_placements("llama3.2-3b", MESH0))
     modeled = eng.placements["decode"].energy_per_token_ws
     assert ctrl.note_metered("decode", modeled * 1.5) is True
     assert eng.energy_correction["decode"] == pytest.approx(1.5)
     _traffic(eng, prefill=2, decode=398, slot_steps=400, active=400)
-    ctrl._on_wave_end(eng)  # drift-forced re-plan replaces the placement
+    ctrl._on_step_end(eng)  # drift-forced re-plan replaces the placement
     assert eng.placements["decode"].source == "adaptive"
     assert "decode" not in eng.energy_correction
     assert "decode" not in ctrl.drift
@@ -302,39 +301,21 @@ def small_model():
     return cfg, params
 
 
-def test_reconfigure_refused_mid_wave(small_model):
-    cfg, params = small_model
-    eng = ServingEngine(cfg, params, slots=2, max_len=32, scheduler="wave")
-    seen = {}
-
-    def hook(engine):
-        engine._in_wave = True  # simulate the forbidden window
-        with pytest.raises(RuntimeError):
-            engine.reconfigure({})
-        engine._in_wave = False
-        engine.reconfigure({})  # between waves: fine
-        seen["ok"] = True
-
-    eng.on_wave_end = hook
-    eng.submit(Request(rid=0, prompt=[1, 2], max_new_tokens=2))
-    eng.run()
-    assert seen["ok"]
-
-
 def test_end_to_end_adaptive_serving_beats_static(small_model, tmp_path):
     cfg, params = small_model
 
     def run_engine(adaptive):
-        eng = ServingEngine(cfg, params, slots=4, max_len=48,
-                            scheduler="wave")
+        eng = ServingEngine(cfg, params, slots=4, max_len=48)
         eng.reconfigure(static_placements("llama3.2-3b", MESH0))
         ctrl = None
         if adaptive:
+            # each request holds its slot 4 steps (its prompt chunk-fed at
+            # admission, then 4 output tokens): re-plan after every batch
             ctrl = PlacementController(
                 eng, "llama3.2-3b", [MESH0, MESH1],
                 cache_path=str(tmp_path / "e2e.jsonl"),
                 ga_config=GAConfig(population=10, generations=8, seed=0),
-                interval_waves=1).attach()
+                interval_steps=4).attach()
         for i in range(12):
             eng.submit(Request(rid=i, prompt=[1 + (i + j) % 11
                                               for j in range(12)],

@@ -29,8 +29,8 @@ def small_model():
 
 
 def test_serving_batched_requests(small_model):
-    """Default scheduler (slot streams): every slot admits the next request
-    the step after its previous one finishes; no wave barrier."""
+    """Slot streams: every slot admits the next request the step after its
+    previous one finishes."""
     cfg, params = small_model
     eng = ServingEngine(cfg, params, slots=4, max_len=48)
     for i in range(6):
@@ -38,7 +38,6 @@ def test_serving_batched_requests(small_model):
     done = eng.run()
     assert len(done) == 6
     assert all(len(r.output) == 5 for r in done)
-    assert eng.stats.waves == 0  # no waves under slot streams
     assert eng.stats.admissions == 6
     # each request: 3 prompt tokens billed as prefill (2 chunk-fed at
     # admission, the last by the admitting step, which already emits the
@@ -48,22 +47,6 @@ def test_serving_batched_requests(small_model):
     assert eng.stats.prefill_chunks == 6
     # 5 steps per request over 4 slots, packed back-to-back: 4 slots serve
     # {2,2,1,1} requests -> 10 steps
-    assert eng.stats.steps == 10
-
-
-def test_serving_wave_scheduler_still_available(small_model):
-    """scheduler="wave" keeps the legacy wave-barrier behavior so existing
-    comparisons stay reproducible."""
-    cfg, params = small_model
-    eng = ServingEngine(cfg, params, slots=4, max_len=48, scheduler="wave")
-    for i in range(6):
-        eng.submit(Request(rid=i, prompt=[1 + i, 2, 3], max_new_tokens=5))
-    done = eng.run()
-    assert len(done) == 6
-    assert eng.stats.waves == 2  # 6 requests over 4 slots
-    assert eng.stats.prefill_tokens == 18
-    assert eng.stats.decode_tokens == 24
-    # both waves run their longest request: 1 admitting step + 4 more
     assert eng.stats.steps == 10
 
 
@@ -110,11 +93,11 @@ def test_serving_empty_queue_is_noop(small_model):
     cfg, params = small_model
     eng = ServingEngine(cfg, params, slots=2, max_len=32)
     assert eng.run() == []
-    assert eng.stats.waves == 0 and eng.stats.steps == 0
+    assert eng.stats.steps == 0
 
 
 def test_serving_rejects_prompt_at_or_over_max_len(small_model):
-    """Pre-PR-2, a prompt >= max_len burned a full wave without completing
+    """A prompt >= max_len used to burn a whole batch without completing
     and was still returned in the done list."""
     cfg, params = small_model
     eng = ServingEngine(cfg, params, slots=2, max_len=16)
@@ -131,7 +114,7 @@ def test_serving_rejects_prompt_at_or_over_max_len(small_model):
 
 def test_serving_rejects_empty_prompt(small_model):
     """An empty prompt has no token to condition on; admitting it used to
-    crash the whole wave (output[-1] on an empty list), taking co-batched
+    crash the whole batch (output[-1] on an empty list), taking co-batched
     requests down with it."""
     cfg, params = small_model
     for policy in ("reject", "truncate"):
@@ -168,7 +151,7 @@ def test_serving_occupancy_tracks_idle_slots(small_model):
     eng = ServingEngine(cfg, params, slots=4, max_len=32)
     eng.submit(Request(rid=0, prompt=[1, 2], max_new_tokens=3))
     eng.run()
-    # 1 of 4 slots busy the whole wave
+    # 1 of 4 slots busy on every step
     assert eng.stats.slot_steps == 4 * eng.stats.steps
     assert eng.stats.occupancy == 0.25
 

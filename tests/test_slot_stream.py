@@ -1,12 +1,10 @@
-"""Slot-stream continuous batching (``scheduler="stream"``) + the PR-4
-serving-ledger fixes.
+"""Slot-stream continuous batching + the serving-ledger fixes.
 
 Exactness: per-slot position streams with masked slot resets
-(``models/transformer.py:reset_decode_slots``) must make the stream
-scheduler's decoded outputs token-identical to the wave scheduler's for any
-fixed request set — across architecture families, including the recurrent
-(RWKV/Mamba) ones whose state carries history densely. Ledger fixes:
-prefill/decode attribution, finish reasons, deque queue draining,
+(``models/transformer.py:reset_decode_slots``) must give every request the
+tokens it gets when served alone — across architecture families, including
+the recurrent (RWKV/Mamba) ones whose state carries history densely. Ledger
+fixes: prefill/decode attribution, finish reasons, deque queue draining,
 placement-epoch energy attribution, SLO-aware admission.
 """
 import dataclasses
@@ -22,17 +20,22 @@ from repro import models as M
 from repro.models import attention as attn_mod
 from repro.models import transformer as T
 from repro.runtime import Placement, Request, ServingEngine
+from test_migration import FAMILIES
+
+
+@functools.cache
+def _model(arch):
+    cfg = reduced(get_config(arch))
+    return cfg, M.init_params(cfg, jax.random.PRNGKey(0))
 
 
 @pytest.fixture(scope="module")
 def small_model():
-    cfg = reduced(get_config("llama3.2-3b"))
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
-    return cfg, params
+    return _model("llama3.2-3b")
 
 
 def _ragged(n=6):
-    """Deliberately ragged lengths: the wave scheduler idles slots on these."""
+    """Deliberately ragged prompt and output lengths."""
     reqs = []
     for i in range(n):
         plen = 2 + (i * 5) % 13
@@ -42,8 +45,8 @@ def _ragged(n=6):
     return reqs
 
 
-def _serve(cfg, params, reqs, scheduler, **kw):
-    eng = ServingEngine(cfg, params, scheduler=scheduler, **kw)
+def _serve(cfg, params, reqs, **kw):
+    eng = ServingEngine(cfg, params, **kw)
     for r in reqs:
         eng.submit(r)
     done = eng.run()
@@ -51,41 +54,60 @@ def _serve(cfg, params, reqs, scheduler, **kw):
 
 
 # ---------------------------------------------------------------------------
-# Exactness: stream == wave, token for token
+# Exactness: batched == alone, token for token
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("arch", ["llama3.2-3b", "rwkv6-1.6b", "zamba2-7b"])
-def test_stream_matches_wave_token_identical(arch):
-    """Dense (KV cache), SSM (recurrent) and hybrid (both): mid-stream
-    admission with per-slot resets changes scheduling only, never tokens."""
-    cfg = reduced(get_config(arch))
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
-    _, wave_done = _serve(cfg, params, _ragged(5), "wave",
-                          slots=2, max_len=24)
-    _, stream_done = _serve(cfg, params, _ragged(5), "stream",
-                            slots=2, max_len=24)
-    assert len(wave_done) == len(stream_done) == 5
-    wave_out = {r.rid: r.output for r in wave_done}
-    stream_out = {r.rid: r.output for r in stream_done}
-    assert wave_out == stream_out
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_stream_matches_alone_token_identical(family):
+    """Every family: two slots with mid-stream admission and per-slot
+    resets change scheduling only, never tokens — each request decodes what
+    it decodes alone on a fresh one-slot engine."""
+    cfg, params = _model(FAMILIES[family])
+    _, done = _serve(cfg, params, _ragged(5), slots=2, max_len=24)
+    assert len(done) == 5
+    alone = {}
+    for req in _ragged(5):
+        _, (r,) = _serve(cfg, params, [req], slots=1, max_len=24)
+        alone[r.rid] = r.output
+    assert {r.rid: r.output for r in done} == alone
 
 
-def test_stream_beats_wave_occupancy_on_ragged_lengths(small_model):
-    """The point of slot streams: freed slots re-admit the next step instead
-    of idling until the wave's longest request drains."""
+def _packed_schedule(durations, slots):
+    """(steps, active slot-steps) of the packed admission rule: every free
+    slot takes the next queued request, which holds it for its duration in
+    steps; a slot freed on step t serves its next request on step t+1."""
+    queue, held, steps, active = list(durations), [], 0, 0
+    while queue or held:
+        while queue and len(held) < slots:
+            held.append(queue.pop(0))
+        steps += 1
+        active += len(held)
+        held = [d - 1 for d in held if d > 1]
+    return steps, active
+
+
+def test_stream_occupancy_matches_packed_admission_on_ragged_lengths(
+        small_model):
+    """The point of slot streams: a freed slot re-admits the next step, so
+    the step count and occupancy are those of packed admission."""
     cfg, params = small_model
-    wave_eng, _ = _serve(cfg, params, _ragged(8), "wave",
-                         slots=3, max_len=32)
-    stream_eng, _ = _serve(cfg, params, _ragged(8), "stream",
-                           slots=3, max_len=32)
-    # identical work ...
-    assert stream_eng.stats.total_tokens == wave_eng.stats.total_tokens
-    # ... on fewer steps at strictly higher occupancy
-    assert stream_eng.stats.steps < wave_eng.stats.steps
-    assert stream_eng.stats.occupancy > wave_eng.stats.occupancy
-    assert stream_eng.stats.waves == 0
-    assert stream_eng.stats.admissions == 8
+    reqs = _ragged(8)
+    # a chunk-prefilled request holds its slot one step per output token;
+    # a token-fed one also one step per prompt token but the last
+    chunked = T.prefill_chunk_supported(cfg)
+    durations = [(1 if chunked else len(r.prompt)) + r.max_new_tokens - 1
+                 for r in reqs]
+    steps, active = _packed_schedule(durations, 3)
+    eng, done = _serve(cfg, params, reqs, slots=3, max_len=32)
+    assert len(done) == 8 and eng.stats.admissions == 8
+    # every prompt token once; each request's first output token rides on
+    # its last prompt token's step
+    assert eng.stats.total_tokens == sum(
+        len(r.prompt) + r.max_new_tokens - 1 for r in reqs)
+    assert eng.stats.steps == steps
+    assert eng.stats.active_slot_steps == active
+    assert eng.stats.occupancy == active / (3 * steps)
 
 
 def test_reset_decode_slots_isolates_streams():
@@ -120,15 +142,16 @@ def test_reset_decode_slots_isolates_streams():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("scheduler", ["wave", "stream"])
-def test_prefill_tokens_equal_prompt_lengths(small_model, scheduler):
+@pytest.mark.parametrize("arch", ["llama3.2-3b", "rwkv6-1.6b"])
+def test_prefill_tokens_equal_prompt_lengths(arch):
     """Regression for the off-by-one: the step consuming the LAST prompt
-    token is prefill, so prefill_tokens == sum of (served) prompt lengths."""
-    cfg, params = small_model
+    token is prefill, so prefill_tokens == sum of (served) prompt lengths —
+    for a chunk-prefilled prompt (dense) and a token-fed one (RWKV)."""
+    cfg, params = _model(arch)
     reqs = _ragged(6)
     prompt_total = sum(len(r.prompt) for r in reqs)
     gen_total = sum(r.max_new_tokens for r in reqs)
-    eng, done = _serve(cfg, params, reqs, scheduler, slots=3, max_len=32)
+    eng, done = _serve(cfg, params, reqs, slots=3, max_len=32)
     assert len(done) == 6
     assert eng.stats.prefill_tokens == prompt_total
     # each request's first generated token rides on its last prefill step
@@ -145,28 +168,28 @@ def test_finish_reason_max_new_tokens_and_eos(small_model):
     cfg, params = small_model
     eng, done = _serve(cfg, params,
                        [Request(rid=0, prompt=[3, 4], max_new_tokens=3)],
-                       "stream", slots=1, max_len=32)
+                       slots=1, max_len=32)
     assert done[0].finish_reason == "max_new_tokens"
     assert eng.stats.length_capped == 0
     first = done[0].output[0]
     eng2, done2 = _serve(cfg, params,
                          [Request(rid=1, prompt=[3, 4], max_new_tokens=3,
                                   eos_id=first)],
-                         "stream", slots=1, max_len=32)
+                         slots=1, max_len=32)
     assert done2[0].finish_reason == "eos"
     assert done2[0].output == [first]
 
 
-@pytest.mark.parametrize("scheduler", ["wave", "stream"])
-def test_length_cap_finish_is_not_a_clean_completion(small_model, scheduler):
+@pytest.mark.parametrize("arch", ["llama3.2-3b", "rwkv6-1.6b"])
+def test_length_cap_finish_is_not_a_clean_completion(arch):
     """A request stopped by the cache filling up used to be marked done
     identically to a clean finish; now it carries finish_reason="length_cap"
-    and is counted in stats.length_capped."""
-    cfg, params = small_model
+    and is counted in stats.length_capped — chunk-prefilled or token-fed."""
+    cfg, params = _model(arch)
     # prompt 10 + wanting 32 more tokens cannot fit max_len=16: the cache
     # caps generation well before max_new_tokens
     req = Request(rid=0, prompt=list(range(1, 11)), max_new_tokens=32)
-    eng, done = _serve(cfg, params, [req], scheduler, slots=1, max_len=16)
+    eng, done = _serve(cfg, params, [req], slots=1, max_len=16)
     assert done == [req] and req.done
     assert req.finish_reason == "length_cap"
     assert len(req.output) < req.max_new_tokens
@@ -184,7 +207,7 @@ def test_large_queue_drains_in_order(small_model):
     list.pop(0) this was quadratic. Smoke a few thousand requests through a
     stubbed decode step and check FIFO admission order is preserved."""
     cfg, _ = small_model
-    eng = ServingEngine(cfg, None, slots=8, max_len=8, scheduler="stream")
+    eng = ServingEngine(cfg, None, slots=8, max_len=8)
     eng._step = lambda params, state, tokens: (
         jnp.zeros((tokens.shape[0], 8), jnp.float32), state)
     n = 3000
@@ -210,7 +233,7 @@ def _placement(kind, e, t=0.0):
 def test_tokens_costed_under_admission_epoch(small_model):
     """Reconfigure while a slot is mid-stream: its tokens keep the epoch it
     was admitted under; the next admission picks up the new placements.
-    This is the invariant that replaces the wave-boundary rule."""
+    This is what makes mid-run reconfiguration safe."""
     cfg, params = small_model
     epoch_a = {"prefill": _placement("prefill", 2.0),
                "decode": _placement("decode", 1.0)}
